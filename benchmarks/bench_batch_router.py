@@ -219,6 +219,8 @@ def main(print_csv: bool = True, batches=(1, 8, 64, 256),
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batches", default="1,8,64,256")
     ap.add_argument("--rounds", type=int, default=30)

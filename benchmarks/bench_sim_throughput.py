@@ -212,6 +212,8 @@ def main(arrivals: int = 1_000_000, lam: float = 2000.0,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arrivals", type=int, default=1_000_000)
     ap.add_argument("--lam", type=float, default=2000.0)

@@ -102,6 +102,8 @@ def main(print_csv: bool = True, smoke: bool = False, windows=None,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="short horizon + two widths (CI)")

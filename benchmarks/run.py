@@ -18,6 +18,8 @@ ALL = ("fig2", "table4", "fig3", "fig4", "table6", "router_us",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=",".join(ALL))
     args = ap.parse_args()

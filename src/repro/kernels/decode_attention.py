@@ -2,12 +2,14 @@
 
 One new query per sequence attends to a cache of C slots whose absolute
 positions arrive as a side input (``kv_pos``; -1 = never written). The
-kernel tiles the cache sequence into VMEM blocks and carries the online
-softmax state (m, l, acc) across the kv-block grid axis — the TPU-native
+kernel tiles the cache sequence into VMEM blocks of every head at once
+(so the TPU block layout is legal for any head count and head_dim) and
+carries each head's online softmax state (m, l, acc) across the
+kv-block grid axis — the TPU-native
 flash-decode: the cache streams HBM->VMEM exactly once, and the fp32
 accumulator never leaves VMEM.
 
-GQA via index_map (q-head -> kv-head h // rep), validity masking from
+GQA by indexing kv-head h // rep inside the block, validity masking from
 kv_pos (handles ring-buffer wraparound and sliding windows without any
 position arithmetic in the layer code).
 
@@ -27,8 +29,8 @@ NEG_INF = -1e30
 
 def _kernel(q_ref, k_ref, v_ref, kvpos_ref, qpos_ref, o_ref,
             m_ref, l_ref, acc_ref, *, scale: float, window: int,
-            softcap: float, n_kv_blocks: int):
-    ikv = pl.program_id(2)
+            softcap: float, rep: int, n_kv_blocks: int):
+    ikv = pl.program_id(1)
 
     @pl.when(ikv == 0)
     def _init():
@@ -36,34 +38,37 @@ def _kernel(q_ref, k_ref, v_ref, kvpos_ref, qpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :]                          # (D,)
-    k = k_ref[0, :, 0, :]                       # (bkv, D)
-    v = v_ref[0, :, 0, :]
-    kv_pos = kvpos_ref[0, :]                    # (bkv,)
-    q_pos = qpos_ref[0]
-
-    s = jnp.sum(k.astype(jnp.float32) * q.astype(jnp.float32)[None, :],
-                axis=1) * scale                 # (bkv,)
-    if softcap > 0:
-        s = jnp.tanh(s / softcap) * softcap
+    kv_pos = kvpos_ref[0]                       # (1, bkv)
+    q_pos = qpos_ref[0]                         # (1, 1)
     valid = (kv_pos >= 0) & (kv_pos <= q_pos)
     if window > 0:
         valid &= kv_pos > (q_pos - window)
-    s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[0] = l_ref[0] * corr + jnp.sum(p)
-    acc_ref[...] = acc_ref[...] * corr + jnp.sum(
-        p[:, None] * v.astype(jnp.float32), axis=0)[None, :]
-    m_ref[0] = m_new
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h:h + 1, :].astype(jnp.float32)          # (1, D)
+        k = k_ref[0, :, h // rep, :].astype(jnp.float32)      # (bkv, D)
+        v = v_ref[0, :, h // rep, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap > 0:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG_INF)                      # (1, bkv)
+
+        m_prev = m_ref[h:h + 1, :]                            # (1, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h:h + 1, :] = l_ref[h:h + 1, :] * corr + jnp.sum(
+            p, axis=1, keepdims=True)
+        acc_ref[h:h + 1, :] = acc_ref[h:h + 1, :] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h:h + 1, :] = m_new
 
     @pl.when(ikv == n_kv_blocks - 1)
     def _finish():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -81,28 +86,34 @@ def decode_attention(q, k_cache, v_cache, kv_pos, q_pos, *, window: int = 0,
     block_kv = min(block_kv, c)
     assert c % block_kv == 0, (c, block_kv)
     n_kv = c // block_kv
-    grid = (b, h, n_kv)
+    grid = (b, n_kv)
 
     kernel = functools.partial(_kernel, scale=scale, window=window,
-                               softcap=softcap, n_kv_blocks=n_kv)
+                               softcap=softcap, rep=rep, n_kv_blocks=n_kv)
+    # one grid step holds every head of a kv block: the last two block
+    # dims are then whole (H, D) / (Hkv, D) planes, which the TPU tiles
+    # for any head count and head_dim; positions ride as (1, 1, .) rows
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda bb, hh, ikv: (bb, hh, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda bb, hh, ikv: (bb, ikv, hh // rep, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda bb, hh, ikv: (bb, ikv, hh // rep, 0)),
-            pl.BlockSpec((1, block_kv), lambda bb, hh, ikv: (bb, ikv)),
-            pl.BlockSpec((1,), lambda bb, hh, ikv: (bb,)),
+            pl.BlockSpec((1, h, d), lambda bb, ikv: (bb, 0, 0)),
+            pl.BlockSpec((1, block_kv, hkv, d),
+                         lambda bb, ikv: (bb, ikv, 0, 0)),
+            pl.BlockSpec((1, block_kv, hkv, d),
+                         lambda bb, ikv: (bb, ikv, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda bb, ikv: (bb, 0, ikv)),
+            pl.BlockSpec((1, 1, 1), lambda bb, ikv: (bb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bb, hh, ikv: (bb, hh, 0)),
+        out_specs=pl.BlockSpec((1, h, d), lambda bb, ikv: (bb, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),        # m
-            pltpu.VMEM((1,), jnp.float32),        # l
-            pltpu.VMEM((1, d), jnp.float32),      # acc
+            pltpu.VMEM((h, 1), jnp.float32),      # m
+            pltpu.VMEM((h, 1), jnp.float32),      # l
+            pltpu.VMEM((h, d), jnp.float32),      # acc
         ],
+        # double-buffered k and v blocks of 512 x (32, 80->128 lanes)
+        # bf16 take ~17 MB, over the 16 MB default scoped VMEM
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
-    )(q, k_cache, v_cache, kv_pos, q_pos)
+    )(q, k_cache, v_cache, kv_pos.reshape(b, 1, c), q_pos.reshape(b, 1, 1))

@@ -2,7 +2,7 @@
 
 TPU adaptation (not a CUDA port): the kernel is expressed as a Pallas
 grid over (batch, q-head, q-block, kv-block) with explicit VMEM
-BlockSpecs. The MXU sees (block_q x D) @ (D x block_kv) tiles —
+BlockSpecs over heads-major (B, H, S, D) views of the BSHD inputs. The MXU sees (block_q x D) @ (D x block_kv) tiles —
 block sizes default to 128 to match the 128x128 systolic array — and
 the online-softmax running state (m, l, acc) lives in VMEM scratch,
 carried across the kv-block grid axis (TPU grids iterate the minor axis
@@ -41,9 +41,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]                      # (bq, D)
-    k = k_ref[0, :, 0, :]                      # (bkv, D)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[0, 0]                            # (bq, D)
+    k = k_ref[0, 0]                            # (bkv, D)
+    v = v_ref[0, 0]
 
     s = jax.lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
                             (((1,), (1,)), ((), ())),
@@ -62,12 +62,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                        # (bq,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                        # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
         p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -75,7 +75,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ikv == n_kv_blocks - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -103,24 +103,29 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         block_q=block_q, block_kv=block_kv, q_offset=skv - sq,
         n_kv_blocks=n_kv)
 
-    return pl.pallas_call(
+    # heads-major views: a block's last two dims are then (sequence, D),
+    # which the TPU tiles for any head_dim (a (1, D) head slice of BSHD
+    # is not a legal block unless H == 1)
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda bb, hh, iq, ikv: (bb, iq, hh, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda bb, hh, iq, ikv: (bb, ikv, hh // rep, 0)),
-            pl.BlockSpec((1, block_kv, 1, d),
-                         lambda bb, hh, iq, ikv: (bb, ikv, hh // rep, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda bb, hh, iq, ikv: (bb, hh, iq, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda bb, hh, iq, ikv: (bb, hh // rep, ikv, 0)),
+            pl.BlockSpec((1, 1, block_kv, d),
+                         lambda bb, hh, iq, ikv: (bb, hh // rep, ikv, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda bb, hh, iq, ikv: (bb, iq, hh, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d),
+                               lambda bb, hh, iq, ikv: (bb, hh, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),      # m: running max
-            pltpu.VMEM((block_q,), jnp.float32),      # l: running sum
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m: running max
+            pltpu.VMEM((block_q, 1), jnp.float32),    # l: running sum
             pltpu.VMEM((block_q, d), jnp.float32),    # acc
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

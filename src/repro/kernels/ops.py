@@ -4,22 +4,25 @@ Every op has three execution paths:
 
 * ``ref``      — the pure-jnp oracle (``repro.kernels.ref``). Default on
                  CPU and for the multi-pod dry-run (fully shardable HLO).
-* ``pallas``   — the Pallas TPU kernel compiled for real (TPU target).
+* ``pallas``   — the Pallas TPU kernel compiled for real. Default when
+                 JAX's default backend is a TPU.
 * ``interp``   — the same Pallas kernel in interpret mode (CPU-correct,
                  used by the kernel test suite).
 
 Select globally via ``set_implementation`` or the REPRO_KERNELS env var,
-or per-call via the ``impl=`` keyword.
+or per-call via the ``impl=`` keyword; with none of them the platform
+decides. There is no fallback: a kernel that does not compile raises.
 """
 from __future__ import annotations
 
 import os
 from typing import Optional
 
+import jax as _jax
 
 from repro.kernels import ref as _ref
 
-_IMPL = os.environ.get("REPRO_KERNELS", "ref")
+_IMPL: Optional[str] = os.environ.get("REPRO_KERNELS")   # None: by platform
 _VALID = ("ref", "pallas", "interp", "fused")
 
 
@@ -31,11 +34,17 @@ def set_implementation(impl: str) -> None:
 
 
 def get_implementation() -> str:
-    return _IMPL
+    return _resolve(None)
 
 
 def _resolve(impl: Optional[str]) -> str:
-    return impl if impl is not None else _IMPL
+    """Explicit ``impl``, else the global choice, else by platform: the
+    compiled kernel on a TPU, the oracle elsewhere."""
+    if impl is not None:
+        return impl
+    if _IMPL is not None:
+        return _IMPL
+    return "pallas" if _jax.default_backend() == "tpu" else "ref"
 
 
 def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
@@ -161,8 +170,6 @@ def routing_attain(lam, alpha, beta, gamma, mu, n, rtt, slo, sigma, avail,
 # the control plane, where retracing the pure-jnp oracle per flush would
 # dominate the decision cost. k/margin are static (they shape the
 # outputs); array shapes are bucketed by the caller (pow2 padding).
-import jax as _jax  # noqa: E402  (after the _ref import by design)
-
 _jit_ref_routing_score = _jax.jit(_ref.routing_score)
 _jit_ref_routing_guard = _jax.jit(_ref.routing_guard)
 _jit_ref_routing_topk = _jax.jit(_ref.routing_topk,

@@ -154,6 +154,31 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 # unstable-pool sentinel the vmap scorer emits (== repro.core.router.BIG)
 _UNSTABLE_G = 1e9
 
+# XLA's f32 rational approximation of erf (numerator odd, denominator
+# even in x, highest power first); |x| >= 4 is +-1 in f32.
+_ERF_ALPHA = (-2.72614225801306e-10, 2.77068142495902e-08,
+              -2.10102402082508e-06, -5.69250639462346e-05,
+              -7.34990630326855e-04, -2.95459980854025e-03,
+              -1.60960333262415e-02)
+_ERF_BETA = (-1.45660718464996e-05, -2.13374055278905e-04,
+             -1.68282697438203e-03, -7.37332916720468e-03,
+             -1.42647390514189e-02)
+
+
+def erf(x: jax.Array) -> jax.Array:
+    """f32 erf from elementwise ops only, so Mosaic lowers it (it has
+    no ``erf`` primitive). ``routing_attain`` and its oracle below both
+    call this one function, which keeps them float-identical."""
+    x = jnp.clip(x.astype(jnp.float32), -4.0, 4.0)
+    x2 = x * x
+    num = jnp.float32(_ERF_ALPHA[0])
+    for c in _ERF_ALPHA[1:]:
+        num = num * x2 + jnp.float32(c)
+    den = jnp.float32(_ERF_BETA[0])
+    for c in _ERF_BETA[1:]:
+        den = den * x2 + jnp.float32(c)
+    return x * num / den
+
 
 def _table_scores(lam: jax.Array, alpha: jax.Array, beta: jax.Array,
                   gamma: jax.Array, mu: jax.Array, n: jax.Array,
@@ -321,7 +346,7 @@ def routing_attain(lam: jax.Array, alpha: jax.Array, beta: jax.Array,
          - jnp.log(jnp.maximum(g, 1e-20))) \
         / (jnp.maximum(sigma[None, :], 1e-20)
            * jnp.float32(1.4142135623730951))
-    phi = 0.5 * (1.0 + jax.scipy.special.erf(jnp.clip(z, -10.0, 10.0)))
+    phi = 0.5 * (1.0 + erf(jnp.clip(z, -10.0, 10.0)))
     p = avail[None, :] * jnp.where(sigma[None, :] > 0.0, phi,
                                    (g <= slo_).astype(jnp.float32))
     p_masked = jnp.where(feasible, p, -1.0)

@@ -20,10 +20,17 @@ decision onto the device:
   breaking toward lower g then lower index, plus the same headroom-gated
   duplicate columns — the ``reliable`` strategy.
 
-Scoring is identical to ``routing_score``: the closed-form latency law
-plus hat-function interpolation of the precomputed per-deployment
-Erlang-C wait table (``build_erlang_table``), so the whole candidate
-table stays VMEM-resident and a window of R decisions is one launch.
+Scoring is shared with ``routing_score`` (whose kernel body and launch
+live here too): the closed-form latency law plus hat-function
+interpolation of the precomputed per-deployment Erlang-C wait table
+(``build_erlang_table``), so the whole candidate table stays
+VMEM-resident and a window of R decisions is one launch.
+
+TPU layout: every block is rank 2 (see :func:`_launch`), so windows of
+several ``block_r`` blocks compile, and the in-kernel argmin / one-hot
+/ any are spelled as masked min/sum reductions over an integer column
+iota, which Mosaic lowers. The attainment kernel's ``erf`` is the f32
+rational approximation in ``ref.erf``, shared with its oracle.
 
 Guard arithmetic is shared: :func:`apply_guard` is the single guard
 surface consumed by the kernel here, by ``guarded.decide``'s fused
@@ -42,8 +49,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.router import BIG as UNSTABLE_G   # 1e9 unstable sentinel
+from repro.kernels.ref import erf
 
-BIG = 1e30          # masking constant for argmin keys (matches routing_score)
+BIG = 1e30          # masking constant for argmin keys
 _SQRT2 = 1.4142135623730951
 ATTAIN_BAND = 1e-6  # absolute attainment tie band (f32-pinned semantics)
 
@@ -64,10 +72,40 @@ def apply_guard(g_home, rtt_home, tau, up, has_up, home):  # laimr-lint: disable
     return target, off
 
 
+def _col_index(shape) -> jax.Array:
+    """Candidate-column index over an (R, I) block. Built from an
+    integer iota: Mosaic has no float iota."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _argmin_cols(key):
+    """Row-wise first-occurrence argmin as an (R, 1) int32 column —
+    ``jnp.argmin(key, axis=1)`` for finite keys, spelled as a masked
+    min over the column index (f32 indices are exact below 2**24)."""
+    col = _col_index(key.shape).astype(jnp.float32)
+    kmin = jnp.min(key, axis=1, keepdims=True)
+    first = jnp.min(jnp.where(key == kmin, col, jnp.float32(key.shape[1])),
+                    axis=1, keepdims=True)
+    return first.astype(jnp.int32)
+
+
+def _pick(values, idx):
+    """values[r, idx[r]] as an (R, 1) column: a one-hot contraction, no
+    gather. ``idx`` is an (R, 1) int32 column."""
+    onehot = (_col_index(values.shape) == idx).astype(jnp.float32)
+    return jnp.sum(values * onehot, axis=1, keepdims=True)
+
+
+def _any_cols(mask):
+    """Row-wise ``any`` as an (R, 1) bool column (f32 max reduction)."""
+    return jnp.max(mask.astype(jnp.float32), axis=1, keepdims=True) > 0.0
+
+
 def _scores(lam, alpha, beta, gamma, mu, n, rtt, table):
-    """(g, rho) over the (R, I) block — identical math to the
-    ``routing_score`` kernel: pow via exp/log, Erlang-C wait via a
-    hat-function weighted contraction against the (I, T) table."""
+    """(g, rho) over the (R, I) block: pow via exp/log, Erlang-C wait
+    via a hat-function weighted contraction against the (I, T) table.
+    ``lam`` is (R, I) or an (R, 1) column; candidate params are (1, I)
+    rows."""
     t = table.shape[1]
     lam_tilde = lam / jnp.maximum(n, 1.0)
     proc = alpha + beta * jnp.exp(
@@ -75,20 +113,28 @@ def _scores(lam, alpha, beta, gamma, mu, n, rtt, table):
     proc = jnp.where(lam_tilde > 0.0, proc, alpha)
     rho = lam / jnp.maximum(n * mu, 1e-12)
     pos = jnp.clip(rho, 0.0, 1.0) * (t - 1)
-    grid = jax.lax.broadcasted_iota(jnp.float32, (1, 1, t), 2)
+    grid = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2).astype(
+        jnp.float32)
     w = jnp.maximum(0.0, 1.0 - jnp.abs(pos[:, :, None] - grid))
     q = jnp.sum(w * table[None, :, :], axis=2)
     return proc + rtt + q, rho
 
 
+def _pack(cols):
+    """(R, 1) columns -> one (R, k) block, by lane selects (no concat)."""
+    shape = (cols[0].shape[0], len(cols))
+    ci = _col_index(shape)
+    out = jnp.broadcast_to(cols[0], shape)
+    for j in range(1, len(cols)):
+        out = jnp.where(ci == j, cols[j], out)
+    return out
+
+
 def _row_params(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
                 rtt_ref, table_ref):
-    lam = lam_ref[...].astype(jnp.float32)
-    if lam.ndim == 1:
-        lam = lam[:, None]
-    return (lam, alpha_ref[...][None, :], beta_ref[...][None, :],
-            gamma_ref[...][None, :], mu_ref[...][None, :],
-            n_ref[...][None, :], rtt_ref[...][None, :], table_ref[...])
+    return (lam_ref[...].astype(jnp.float32), alpha_ref[...],
+            beta_ref[...], gamma_ref[...], mu_ref[...], n_ref[...],
+            rtt_ref[...], table_ref[...])
 
 
 def _guard_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
@@ -103,60 +149,74 @@ def _guard_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
     g_eff = jnp.where(rho < 1.0, g, jnp.float32(UNSTABLE_G))
     home = home_ref[...]
     up = up_ref[...]
-    hh = jax.nn.one_hot(home, g.shape[1], dtype=jnp.float32)
-    g_home = jnp.sum(g_eff * hh, axis=1)
-    rtt_home = jnp.sum(jnp.broadcast_to(rtt, g.shape) * hh, axis=1)
+    g_home = _pick(g_eff, home)
+    rtt_home = _pick(jnp.broadcast_to(rtt, g.shape), home)
     target, off = apply_guard(g_home, rtt_home, tau_ref[...],
                               up, up >= 0, home)
-    th = jax.nn.one_hot(target, g.shape[1], dtype=jnp.float32)
     idx_ref[...] = target.astype(jnp.int32)
-    g_ref[...] = jnp.sum(g_eff * th, axis=1)
-    off_ref[...] = off
+    g_ref[...] = _pick(g_eff, target)
+    off_ref[...] = off.astype(jnp.int32)
 
 
 def _primary_route_best(g, rho, slo, cost):
     """route_best's pinned two-stage selection over a scored block:
     feasibility, masked latency argmin with the 1e-5 near band, cost
-    argmin among near-ties (first occurrence = stable by index)."""
+    argmin among near-ties (first occurrence = stable by index).
+    Returns (primary (R, 1), feasible (R, I))."""
     feasible = (rho < 1.0) & (g <= slo)
     g_masked = jnp.where(feasible, g, BIG)
     gmin = jnp.min(g_masked, axis=1, keepdims=True)
     near = feasible & (g_masked <= gmin * (1.0 + 1e-5) + 1e-9)
     key = jnp.where(near, cost, BIG)
-    return jnp.argmin(key, axis=1), feasible
+    return _argmin_cols(key), feasible
+
+
+def _score_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
+                  rtt_ref, slo_ref, cost_ref, table_ref,
+                  idx_ref, g_ref, ok_ref):
+    lam, alpha, beta, gamma, mu, n, rtt, table = _row_params(
+        lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref, rtt_ref,
+        table_ref)
+    g, rho = _scores(lam, alpha, beta, gamma, mu, n, rtt, table)
+    primary, feasible = _primary_route_best(g, rho, slo_ref[...],
+                                            cost_ref[...])
+    idx_ref[...] = primary
+    g_ref[...] = _pick(g, primary)
+    ok_ref[...] = _any_cols(feasible).astype(jnp.int32)
 
 
 def _dup_columns(g, start_mask, k):
     """k - 1 duplicate columns by iterative masked argmin over
     ``start_mask`` — ascending g, ties to the lowest index (argmin's
     first occurrence, matching np.argsort(kind="stable"))."""
+    col = _col_index(g.shape)
     remaining = start_mask
     cols, gcols = [], []
     for _ in range(k - 1):
-        gm = jnp.where(remaining, g, BIG)
-        ij = jnp.argmin(gm, axis=1)
-        has = jnp.any(remaining, axis=1)
-        jh = jax.nn.one_hot(ij, g.shape[1], dtype=jnp.float32) \
-            * has[:, None].astype(jnp.float32)
-        cols.append(jnp.where(has, ij, -1).astype(jnp.int32))
-        gcols.append(jnp.sum(g * jh, axis=1))
-        remaining = remaining & (jh < 0.5)
+        ij = _argmin_cols(jnp.where(remaining, g, BIG))
+        has = _any_cols(remaining)
+        jh = (col == ij) & has
+        cols.append(jnp.where(has, ij, -1))
+        gcols.append(jnp.sum(g * jh.astype(jnp.float32), axis=1,
+                             keepdims=True))
+        remaining = remaining & ~jh
     return cols, gcols
 
 
 def _finish_topk(g, rho, feasible, primary, ok, gate, k,
                  idx_ref, g_ref, ok_ref):
     """Emit the (R, K) outputs shared by the topk/attain kernels."""
-    ph = jax.nn.one_hot(primary, g.shape[1], dtype=jnp.float32)
     g_eff = jnp.where(rho < 1.0, g, jnp.float32(UNSTABLE_G))
     # infeasible rows report the row-minimum score (the vmap policies'
     # ``predicted = min(g[r])`` fallback) in column 0
-    g0 = jnp.where(ok, jnp.sum(g * ph, axis=1), jnp.min(g_eff, axis=1))
-    idx0 = jnp.where(ok, primary, -1).astype(jnp.int32)
-    cols, gcols = _dup_columns(g, feasible & gate & (ph < 0.5), k)
-    idx_ref[...] = jnp.stack([idx0] + cols, axis=1)
-    g_ref[...] = jnp.stack([g0] + gcols, axis=1)
-    ok_ref[...] = ok
+    g0 = jnp.where(ok, _pick(g, primary),
+                   jnp.min(g_eff, axis=1, keepdims=True))
+    idx0 = jnp.where(ok, primary, -1)
+    not_primary = _col_index(g.shape) != primary
+    cols, gcols = _dup_columns(g, feasible & gate & not_primary, k)
+    idx_ref[...] = _pack([idx0] + cols)
+    g_ref[...] = _pack([g0] + gcols)
+    ok_ref[...] = ok.astype(jnp.int32)
 
 
 def _topk_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
@@ -166,12 +226,9 @@ def _topk_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
         lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref, rtt_ref,
         table_ref)
     slo = slo_ref[...]
-    if slo.ndim == 1:
-        slo = slo[None, :]
-    cost = cost_ref[...][None, :]
     g, rho = _scores(lam, alpha, beta, gamma, mu, n, rtt, table)
-    primary, feasible = _primary_route_best(g, rho, slo, cost)
-    ok = jnp.any(feasible, axis=1)
+    primary, feasible = _primary_route_best(g, rho, slo, cost_ref[...])
+    ok = _any_cols(feasible)
     gate = g <= slo - jnp.float32(margin)
     _finish_topk(g, rho, feasible, primary, ok, gate, k,
                  idx_ref, g_ref, ok_ref)
@@ -184,23 +241,21 @@ def _attain_kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
         lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref, rtt_ref,
         table_ref)
     slo = slo_ref[...]
-    if slo.ndim == 1:
-        slo = slo[None, :]
-    sigma = sigma_ref[...][None, :]
-    avail = avail_ref[...][None, :]
+    sigma = sigma_ref[...]
+    avail = avail_ref[...]
     g, rho = _scores(lam, alpha, beta, gamma, mu, n, rtt, table)
     feasible = (rho < 1.0) & (g <= slo)
     # delivery-weighted attainment, f32 end to end (decision precision)
     z = (jnp.log(jnp.maximum(slo, 1e-20)) - jnp.log(jnp.maximum(g, 1e-20))
          ) / (jnp.maximum(sigma, 1e-20) * jnp.float32(_SQRT2))
-    phi = 0.5 * (1.0 + jax.lax.erf(jnp.clip(z, -10.0, 10.0)))
+    phi = 0.5 * (1.0 + erf(jnp.clip(z, -10.0, 10.0)))
     p = avail * jnp.where(sigma > 0.0, phi,
                           (g <= slo).astype(jnp.float32))
     p_masked = jnp.where(feasible, p, -1.0)
     pmax = jnp.max(p_masked, axis=1, keepdims=True)
     nearp = feasible & (p_masked >= pmax - jnp.float32(ATTAIN_BAND))
-    primary = jnp.argmin(jnp.where(nearp, g, BIG), axis=1)
-    ok = jnp.any(feasible, axis=1)
+    primary = _argmin_cols(jnp.where(nearp, g, BIG))
+    ok = _any_cols(feasible)
     gate = g <= slo - jnp.float32(margin)
     _finish_topk(g, rho, feasible, primary, ok, gate, k,
                  idx_ref, g_ref, ok_ref)
@@ -211,35 +266,40 @@ def _launch(kernel, lam, inputs, table, out_shapes, block_r, interpret):
     candidate table + Erlang table resident per block. ``inputs`` is a
     list of ``(array, kind)`` with kind "cand" (an (I,) column, resident
     in full) or "req" (per-request rows, blocked over R — (R,) or
-    (R, I) by the array's ndim)."""
+    (R, I) by the array's ndim). ``out_shapes`` lists the public
+    ``(shape, dtype)`` of each output.
+
+    Every block is rank 2, so any ``block_r`` that is a multiple of 8
+    (or the whole window) tiles legally on the TPU: per-request vectors
+    travel as (R, 1) columns, candidate columns as (1, I) rows, and bool
+    outputs as int32. The public 1-D/bool forms are restored here."""
     r = lam.shape[0]
     i, t = table.shape
     block_r = min(block_r, r)
     assert r % block_r == 0, (r, block_r)
-    full = lambda _: (0,)
 
-    def req_spec(arr):
-        return pl.BlockSpec((block_r,), lambda ir: (ir,)) \
-            if arr.ndim == 1 else pl.BlockSpec((block_r, i),
-                                               lambda ir: (ir, 0))
+    def req(arr):
+        a = arr.reshape(r, 1) if arr.ndim == 1 else arr
+        return a, pl.BlockSpec((block_r, a.shape[1]), lambda ir: (ir, 0))
 
-    in_specs = [req_spec(lam)]
+    pairs = [req(lam)]
     for arr, kind in inputs:
-        in_specs.append(pl.BlockSpec((i,), full) if kind == "cand"
-                        else req_spec(arr))
-    in_specs.append(pl.BlockSpec((i, t), lambda ir: (0, 0)))
+        pairs.append((arr.reshape(1, i), pl.BlockSpec((1, i), lambda ir: (0, 0)))
+                     if kind == "cand" else req(arr))
+    pairs.append((table, pl.BlockSpec((i, t), lambda ir: (0, 0))))
+    args, in_specs = zip(*pairs)
     out_specs, shapes = [], []
     for shape, dtype in out_shapes:
-        if len(shape) == 1:
-            out_specs.append(pl.BlockSpec((block_r,), lambda ir: (ir,)))
-        else:
-            out_specs.append(
-                pl.BlockSpec((block_r, shape[1]), lambda ir: (ir, 0)))
-        shapes.append(jax.ShapeDtypeStruct(shape, dtype))
-    return pl.pallas_call(
-        kernel, grid=(r // block_r,), in_specs=in_specs,
+        w = shape[1] if len(shape) == 2 else 1
+        out_specs.append(pl.BlockSpec((block_r, w), lambda ir: (ir, 0)))
+        shapes.append(jax.ShapeDtypeStruct(
+            (r, w), jnp.int32 if dtype == jnp.bool_ else dtype))
+    outs = pl.pallas_call(
+        kernel, grid=(r // block_r,), in_specs=list(in_specs),
         out_specs=out_specs, out_shape=shapes, interpret=interpret,
-    )(lam, *[a for a, _ in inputs], table)
+    )(*args)
+    return tuple((o != 0 if dtype == jnp.bool_ else o).reshape(shape)
+                 for o, (shape, dtype) in zip(outs, out_shapes))
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))

@@ -19,6 +19,9 @@ TPU adaptation notes:
   this memory system hates.
 * Tie-break-by-cost argmin is fused: key = (is_feasible, g, cost)
   lexicographic via masked min.
+* The kernel body and the TPU block layout are shared with the
+  whole-policy kernels in ``routing_decide`` (``_score_kernel``,
+  ``_launch``).
 
 Oracle: ``repro.kernels.ref.routing_score``.
 """
@@ -28,54 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-BIG = 1e30
-
-
-def _kernel(lam_ref, alpha_ref, beta_ref, gamma_ref, mu_ref, n_ref,
-            rtt_ref, slo_ref, cost_ref, table_ref,
-            idx_ref, g_ref, ok_ref):
-    lam = lam_ref[...].astype(jnp.float32)               # (R,) or (R, I)
-    if lam.ndim == 1:
-        lam = lam[:, None]                               # (R, 1) broadcast
-    alpha = alpha_ref[...][None, :]                      # (1, I)
-    beta = beta_ref[...][None, :]
-    gamma = gamma_ref[...][None, :]
-    mu = mu_ref[...][None, :]
-    n = n_ref[...][None, :]
-    rtt = rtt_ref[...][None, :]
-    slo = slo_ref[...]                                   # (I,) or (R, I)
-    if slo.ndim == 1:
-        slo = slo[None, :]                               # shared budget rows
-    cost = cost_ref[...][None, :]
-    table = table_ref[...]                               # (I, T)
-    t = table.shape[1]
-
-    lam_tilde = lam / jnp.maximum(n, 1.0)
-    proc = alpha + beta * jnp.exp(
-        gamma * jnp.log(jnp.maximum(lam_tilde, 1e-20)))  # pow via exp/log
-    proc = jnp.where(lam_tilde > 0.0, proc, alpha)
-
-    rho = lam / jnp.maximum(n * mu, 1e-12)               # (R, I)
-    pos = jnp.clip(rho, 0.0, 1.0) * (t - 1)              # table coordinate
-    # hat-function interpolation: w[r,i,t] = max(0, 1 - |pos - t|)
-    grid = jax.lax.broadcasted_iota(jnp.float32, (1, 1, t), 2)
-    w = jnp.maximum(0.0, 1.0 - jnp.abs(pos[:, :, None] - grid))  # (R, I, T)
-    q = jnp.sum(w * table[None, :, :], axis=2)           # (R, I)
-
-    g = proc + rtt + q
-    feasible = (rho < 1.0) & (g <= slo)
-    g_masked = jnp.where(feasible, g, BIG)
-    gmin = jnp.min(g_masked, axis=1, keepdims=True)
-    near = feasible & (g_masked <= gmin * (1.0 + 1e-5) + 1e-9)
-    key = jnp.where(near, cost, BIG)
-    idx_ref[...] = jnp.argmin(key, axis=1).astype(jnp.int32)
-    # best g for the chosen index via one-hot (gather-free)
-    onehot = jax.nn.one_hot(jnp.argmin(key, axis=1), g.shape[1],
-                            dtype=jnp.float32)
-    g_ref[...] = jnp.sum(g * onehot, axis=1)
-    ok_ref[...] = jnp.any(feasible, axis=1)
+from repro.kernels.routing_decide import _launch, _score_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -93,39 +50,13 @@ def routing_score(lam, alpha, beta, gamma, mu, n, rtt, slo, cost,
     erlang_c_table: (I, T) precomputed waits over a rho grid.
     Returns (idx (R,), best_g (R,), feasible (R,))."""
     r = lam.shape[0]
-    i, t = erlang_c_table.shape
-    block_r = min(block_r, r)
-    assert r % block_r == 0, (r, block_r)
-    grid = (r // block_r,)
-
-    lam_spec = pl.BlockSpec((block_r,), lambda ir: (ir,)) if lam.ndim == 1 \
-        else pl.BlockSpec((block_r, i), lambda ir: (ir, 0))
-    full = lambda _: (0,)
-    slo_spec = pl.BlockSpec((i,), full) if slo.ndim == 1 \
-        else pl.BlockSpec((block_r, i), lambda ir: (ir, 0))
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            lam_spec,
-            pl.BlockSpec((i,), full), pl.BlockSpec((i,), full),
-            pl.BlockSpec((i,), full), pl.BlockSpec((i,), full),
-            pl.BlockSpec((i,), full), pl.BlockSpec((i,), full),
-            slo_spec, pl.BlockSpec((i,), full),
-            pl.BlockSpec((i, t), lambda ir: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_r,), lambda ir: (ir,)),
-            pl.BlockSpec((block_r,), lambda ir: (ir,)),
-            pl.BlockSpec((block_r,), lambda ir: (ir,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r,), jnp.int32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.bool_),
-        ],
-        interpret=interpret,
-    )(lam, alpha, beta, gamma, mu, n, rtt, slo, cost, erlang_c_table)
+    cand = [(c, "cand") for c in (alpha, beta, gamma, mu, n, rtt)]
+    return _launch(
+        _score_kernel, lam,
+        cand + [(slo, "cand" if slo.ndim == 1 else "req"), (cost, "cand")],
+        erlang_c_table,
+        [((r,), jnp.int32), ((r,), jnp.float32), ((r,), jnp.bool_)],
+        block_r, interpret)
 
 
 def build_erlang_table(mu, n, t: int = 65):  # laimr-lint: disable=kernel-oracle -- shared table builder, not a kernel: both routing_score paths (Pallas and ref.py) consume its output, and the kernel-vs-oracle sweeps in test_kernels exercise it on every case

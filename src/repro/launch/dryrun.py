@@ -32,7 +32,7 @@ import numpy as np
 from repro.configs.base import ARCH_IDS, SHAPES, ArchConfig, get_config
 from repro.distributed import sharding
 from repro.launch import hlo_analysis, specs
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 
 # --------------------------------------------------------------- skips
 LONG_OK = {"mamba2_370m", "recurrentgemma_2b", "gemma2_27b"}
@@ -92,7 +92,7 @@ def run_one(arch_id: str, shape_name: str, mesh_kind: str,
     cfg = config_for(arch_id, shape_name)
     if mesh_shape is not None:
         rec["mesh_shape"] = list(mesh_shape)
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     t0 = time.time()

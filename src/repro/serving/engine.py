@@ -64,6 +64,8 @@ class ServingEngine:
         self.current = jnp.zeros((slots,), jnp.int32)
         self._decode = jax.jit(
             lambda p, t, c, q: model.decode_step(p, self.cfg, t, c, q))
+        self._prefill = jax.jit(
+            lambda p, bb: model.prefill(p, self.cfg, bb))
 
     def free_slots(self) -> list[int]:
         return [i for i in range(self.slots) if not self.active[i]]
@@ -115,12 +117,9 @@ class ServingEngine:
         assert b <= self.slots
         batch = {"tokens": prompts} if self.cfg.frontend == "tokens" else \
             {"embeddings": prompts}
-        logits, cache = jax.jit(
-            lambda p, bb: model.prefill(p, self.cfg, bb))(self.params, batch)
+        logits, cache = self._prefill(self.params, batch)
         # move the prefilled cache into the engine slots (b == slots fast
-        # path). NOTE: _merge_batch builds its index tuple explicitly —
-        # PEP-646 star-unpacking inside a subscript is a SyntaxError on
-        # Python 3.10, which this repo still supports.
+        # path adopts it whole)
         if b == self.slots:
             self.cache = cache
         else:

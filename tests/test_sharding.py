@@ -14,13 +14,11 @@ from repro.launch import hlo_analysis
 
 
 def mesh16():
-    # jax 0.4.37's AbstractMesh takes ((name, size), ...) pairs, not a
-    # bare shape tuple + names.
-    return AbstractMesh((("data", 16), ("model", 16)))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh_multipod():
-    return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestParamSpec:
