@@ -1,0 +1,4 @@
+"""``submit_lag_p99_ms.route`` in the cells without batching."""
+from bench.harness import reader
+
+read = reader("submit_lag_p99_ms.route")
