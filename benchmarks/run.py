@@ -12,9 +12,9 @@ import argparse
 import os
 import time
 
-ALL = ("fig2", "table4", "fig3", "fig4", "table6", "router_us",
-       "batch_router", "window_sweep", "policy_matrix", "capacity",
-       "sim_throughput", "roofline")
+ALL = ("fig2", "table4", "fig3", "fig4", "table6", "batch_router",
+       "window_sweep", "policy_matrix", "capacity", "sim_throughput",
+       "roofline")
 
 
 def main() -> None:
@@ -39,8 +39,6 @@ def main() -> None:
                 from benchmarks import bench_fig4 as m
             elif name == "table6":
                 from benchmarks import bench_table6 as m
-            elif name == "router_us":
-                from benchmarks import bench_router_us as m
             elif name == "batch_router":
                 from benchmarks import bench_batch_router as m
             elif name == "window_sweep":

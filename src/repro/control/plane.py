@@ -41,6 +41,7 @@ from repro.core.autoscaler import PMHPA
 from repro.core.catalogue import Cluster, Deployment
 from repro.core.router import Router, RouterParams
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 def hpa_refresh(router: Router, pmhpa: PMHPA, t_now: float,
@@ -241,13 +242,19 @@ class ControlPlane:
         reqs = self.queue.drain()
         if not reqs:
             return []
-        pol = self.policy
-        dec = pol.decide(reqs, t_now)
-        self.flushes += 1
-        self.scored_pairs += dec.lam.shape[0] * dec.lam.shape[1]
-        self.decided += len(reqs)
+        with span("plane.flush", flush=self.flushes + 1, rows=len(reqs)):
+            dec = self.policy.decide(reqs, t_now)
+            self.flushes += 1
+            self.scored_pairs += dec.lam.shape[0] * dec.lam.shape[1]
+            self.decided += len(reqs)
+            with span("plane.bind"):
+                return self._bind_window(reqs, dec, t_now)
 
-        deps = pol.deps
+    def _bind_window(self, reqs: list[Request], dec,
+                     t_now: float) -> list[AdmissionDecision]:
+        """Bind a decided window in decision order, each request followed
+        by its redundant copies."""
+        deps = self.policy.deps
         out: list[AdmissionDecision] = []
         for r, req in enumerate(reqs):
             pred = float(dec.predicted[r])

@@ -68,6 +68,7 @@ from repro.core.router import (BIG, Router, score_instance_scalar,
                                score_instances_batch, select_instance_batch,
                                select_instance_scalar)
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 class CandidateTable:
@@ -274,14 +275,24 @@ class RoutingPolicyBase:
         primary in column 0, the next k-1 feasible candidates ascending
         by g (headroom-gated by ``margin``) after it, -1 padding.
         Returns host (idx (R, k), g (R, k), ok (R,))."""
+        with span("policy.upload"):
+            cols = self._device_static()
+            lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
+            erlang = self._erlang()
+        return self._launch(
+            "routing_topk", r, lam_d, cols["alpha"], cols["beta"],
+            cols["gamma"], cols["mu"], cols["n"], cols["rtt"], slo_d,
+            cols["cost"], erlang, k=k, margin=float(margin), block_r=block)
+
+    def _launch(self, kernel: str, r: int, *args, **static) -> tuple:
+        """One launch of the fused kernel ``ops.<kernel>`` (looked up at
+        call time) over device inputs; returns its outputs as host
+        arrays cut to the window's ``r`` rows."""
         from repro.kernels import ops
-        cols = self._device_static()
-        lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
-        idx, g, ok = ops.routing_topk(
-            lam_d, cols["alpha"], cols["beta"], cols["gamma"], cols["mu"],
-            cols["n"], cols["rtt"], slo_d, cols["cost"], self._erlang(),
-            k=k, margin=float(margin), impl=self._impl(), block_r=block)
-        return np.asarray(idx)[:r], np.asarray(g)[:r], np.asarray(ok)[:r]
+        with span("kernel.launch"):
+            outs = getattr(ops, kernel)(*args, impl=self._impl(), **static)
+        with span("policy.readback"):
+            return tuple(np.asarray(o)[:r] for o in outs)
 
     # ---------------- strategy hook ----------------------------------- #
     def decide(self, reqs: list[Request], t_now: float) -> WindowDecision:
@@ -378,15 +389,14 @@ class RoutingPolicyBase:
         restrictions fold into the SLO rows — an excluded candidate gets
         slo = -1, and g >= 0 always, so it is infeasible exactly as the
         vmap path's ``(g <= slo) & mask``."""
-        from repro.kernels import ops
-        cols = self._device_static()
-        lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
-        idx, g_best, ok = ops.routing_score(
-            lam_d, cols["alpha"], cols["beta"], cols["gamma"], cols["mu"],
-            cols["n"], cols["rtt"], slo_d, cols["cost"], self._erlang(),
-            impl=self._impl(), block_r=block)
-        return (np.asarray(idx)[:r], np.asarray(g_best)[:r],
-                np.asarray(ok)[:r])
+        with span("policy.upload"):
+            cols = self._device_static()
+            lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
+            erlang = self._erlang()
+        return self._launch(
+            "routing_score", r, lam_d, cols["alpha"], cols["beta"],
+            cols["gamma"], cols["mu"], cols["n"], cols["rtt"], slo_d,
+            cols["cost"], erlang, block_r=block)
 
     # ---------------- home-tier binding (guard strategies) ------------ #
     def home_index(self, req: Request) -> int:

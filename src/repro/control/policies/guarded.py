@@ -28,6 +28,7 @@ import numpy as np
 from repro.control.policies.base import (BIG, RoutingPolicyBase,
                                          WindowDecision)
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 class GuardedAlgorithm1Policy(RoutingPolicyBase):
@@ -42,40 +43,40 @@ class GuardedAlgorithm1Policy(RoutingPolicyBase):
         tentpole) — no (R, I) matrix ever reaches the host. Padded rows
         carry up = -1 so the guard holds them home; they are sliced off.
         Returns host (primary (R,) int64, g_sel (R,), offload (R,))."""
-        from repro.kernels import ops
         import jax.numpy as jnp
-        cols = self._device_static()
-        r = lam.shape[0]
-        block, padded = self._pad_block(r)
-        lam32 = lam.astype(np.float32)
-        tau32 = tau.astype(np.float32)
-        home32 = home.astype(np.int32)
-        up32 = up.astype(np.int32)
-        if padded > r:
-            pad = padded - r
-            lam32 = np.concatenate(
-                [lam32, np.zeros((pad, lam.shape[1]), np.float32)])
-            tau32 = np.concatenate([tau32, np.zeros(pad, np.float32)])
-            home32 = np.concatenate([home32, np.zeros(pad, np.int32)])
-            up32 = np.concatenate([up32, np.full(pad, -1, np.int32)])
-        idx, g_sel, off = ops.routing_guard(
-            jnp.asarray(lam32), cols["alpha"], cols["beta"], cols["gamma"],
-            cols["mu"], cols["n"], cols["rtt"], jnp.asarray(tau32),
-            jnp.asarray(home32), jnp.asarray(up32), self._erlang(),
-            impl=self._impl(), block_r=block)
-        return (np.asarray(idx)[:r].astype(np.int64),
-                np.asarray(g_sel)[:r], np.asarray(off)[:r])
+        with span("policy.upload"):
+            cols = self._device_static()
+            r = lam.shape[0]
+            block, padded = self._pad_block(r)
+            lam32 = lam.astype(np.float32)
+            tau32 = tau.astype(np.float32)
+            home32 = home.astype(np.int32)
+            up32 = up.astype(np.int32)
+            if padded > r:
+                pad = padded - r
+                lam32 = np.concatenate(
+                    [lam32, np.zeros((pad, lam.shape[1]), np.float32)])
+                tau32 = np.concatenate([tau32, np.zeros(pad, np.float32)])
+                home32 = np.concatenate([home32, np.zeros(pad, np.int32)])
+                up32 = np.concatenate([up32, np.full(pad, -1, np.int32)])
+            args = (jnp.asarray(lam32), cols["alpha"], cols["beta"],
+                    cols["gamma"], cols["mu"], cols["n"], cols["rtt"],
+                    jnp.asarray(tau32), jnp.asarray(home32),
+                    jnp.asarray(up32), self._erlang())
+        idx, g_sel, off = self._launch("routing_guard", r, *args,
+                                       block_r=block)
+        return idx.astype(np.int64), g_sel, off
 
     def decide(self, reqs: list[Request], t_now: float) -> WindowDecision:
-        lam = self.lam_matrix(reqs, t_now)
-        slo = self.slo_rows(reqs)
-        mask = self.mask_rows(reqs)
-
         tbl = self.table
-        rows = np.arange(len(reqs))
-        home = np.array([self.home_index(rq) for rq in reqs], np.int64)
-        up = tbl.upstream[home]                       # -1 at the top tier
-        tau = slo[rows, home]
+        with span("policy.rates"):
+            lam = self.lam_matrix(reqs, t_now)
+            slo = self.slo_rows(reqs)
+            mask = self.mask_rows(reqs)
+            rows = np.arange(len(reqs))
+            home = np.array([self.home_index(rq) for rq in reqs], np.int64)
+            up = tbl.upstream[home]                   # -1 at the top tier
+            tau = slo[rows, home]
         if self.fused:
             # whole decision in one kernel launch; the plane re-scores
             # lazily through score_row on the rare engine-overflow path

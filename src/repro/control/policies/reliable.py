@@ -41,6 +41,7 @@ import numpy as np
 from repro.control.policies.base import RoutingPolicyBase, WindowDecision
 from repro.core.latency_model import slo_attain_prob
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 class ReliableSloPolicy(RoutingPolicyBase):
@@ -71,25 +72,26 @@ class ReliableSloPolicy(RoutingPolicyBase):
         delivery-weighted attainment probability, duplicate columns
         headroom-gated, the (R, I) matrix device-only. Returns host
         (idx (R, k), g (R, k), ok (R,))."""
-        from repro.kernels import ops
         import jax.numpy as jnp
-        if self._dist_cols is None:
-            self._dist_cols = (jnp.asarray(self._sigma, jnp.float32),
-                               jnp.asarray(self._avail, jnp.float32))
-            self.host_uploads += 2
-        sigma, avail = self._dist_cols
-        cols = self._device_static()
-        lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
-        idx, g, ok = ops.routing_attain(
-            lam_d, cols["alpha"], cols["beta"], cols["gamma"], cols["mu"],
-            cols["n"], cols["rtt"], slo_d, sigma, avail, self._erlang(),
-            k=k, margin=float(margin), impl=self._impl(), block_r=block)
-        return np.asarray(idx)[:r], np.asarray(g)[:r], np.asarray(ok)[:r]
+        with span("policy.upload"):
+            if self._dist_cols is None:
+                self._dist_cols = (jnp.asarray(self._sigma, jnp.float32),
+                                   jnp.asarray(self._avail, jnp.float32))
+                self.host_uploads += 2
+            sigma, avail = self._dist_cols
+            cols = self._device_static()
+            lam_d, slo_d, r, block = self._fused_rows(lam, slo, mask)
+            erlang = self._erlang()
+        return self._launch(
+            "routing_attain", r, lam_d, cols["alpha"], cols["beta"],
+            cols["gamma"], cols["mu"], cols["n"], cols["rtt"], slo_d, sigma,
+            avail, erlang, k=k, margin=float(margin), block_r=block)
 
     def decide(self, reqs: list[Request], t_now: float) -> WindowDecision:
-        lam = self.lam_matrix(reqs, t_now)
-        slo = self.slo_rows(reqs)
-        mask = self.mask_rows(reqs)
+        with span("policy.rates"):
+            lam = self.lam_matrix(reqs, t_now)
+            slo = self.slo_rows(reqs)
+            mask = self.mask_rows(reqs)
         k_extra = max(int(self.cfg.redundancy) - 1, 0)
         margin = float(self.cfg.headroom_margin)
         r_n = len(reqs)

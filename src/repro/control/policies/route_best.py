@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.control.policies.base import RoutingPolicyBase, WindowDecision
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 class RouteBestPolicy(RoutingPolicyBase):
@@ -26,9 +27,10 @@ class RouteBestPolicy(RoutingPolicyBase):
     name = "route_best"
 
     def decide(self, reqs: list[Request], t_now: float) -> WindowDecision:
-        lam = self.lam_matrix(reqs, t_now)
-        slo = self.slo_rows(reqs)
-        mask = self.mask_rows(reqs)
+        with span("policy.rates"):
+            lam = self.lam_matrix(reqs, t_now)
+            slo = self.slo_rows(reqs)
+            mask = self.mask_rows(reqs)
         idx, ok, g_best, g = self.score_select(lam, slo, mask)
 
         r_n = len(reqs)

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.control.policies.base import RoutingPolicyBase, WindowDecision
 from repro.core.scheduler import Request
+from repro.tracing import span
 
 
 class SafeTailRedundantPolicy(RoutingPolicyBase):
@@ -41,9 +42,10 @@ class SafeTailRedundantPolicy(RoutingPolicyBase):
     name = "safetail"
 
     def decide(self, reqs: list[Request], t_now: float) -> WindowDecision:
-        lam = self.lam_matrix(reqs, t_now)
-        slo = self.slo_rows(reqs)
-        mask = self.mask_rows(reqs)
+        with span("policy.rates"):
+            lam = self.lam_matrix(reqs, t_now)
+            slo = self.slo_rows(reqs)
+            mask = self.mask_rows(reqs)
         k_extra = max(int(self.cfg.redundancy) - 1, 0)
         r_n = len(reqs)
 

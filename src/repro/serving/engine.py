@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models import model
+from repro.tracing import span
 
 PyTree = Any
 
@@ -104,12 +105,15 @@ class ServingEngine:
 
     def step(self) -> np.ndarray:
         """One decode step for all slots; returns the new tokens (B,)."""
-        logits, self.cache = self._decode(self.params, self.current,
-                                          self.cache, self.pos)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self.current = nxt
-        self.pos = self.pos + 1
-        return np.asarray(nxt)
+        with span("engine.step"):
+            with span("engine.dispatch"):
+                logits, self.cache = self._decode(self.params, self.current,
+                                                  self.cache, self.pos)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                self.current = nxt
+                self.pos = self.pos + 1
+            with span("engine.readback"):
+                return np.asarray(nxt)
 
     def generate(self, prompts: jax.Array, steps: int) -> GenerationResult:
         """Prefill ``prompts`` (B<=slots, S) then greedy-decode ``steps``."""
@@ -120,12 +124,13 @@ class ServingEngine:
         logits, cache = self._prefill(self.params, batch)
         # move the prefilled cache into the engine slots (b == slots fast
         # path adopts it whole)
-        if b == self.slots:
-            self.cache = cache
-        else:
-            self.cache = jax.tree.map(
-                lambda full, new: _merge_batch(full, new, b),
-                self.cache, cache)
+        with span("engine.merge"):
+            if b == self.slots:
+                self.cache = cache
+            else:
+                self.cache = jax.tree.map(
+                    lambda full, new: _merge_batch(full, new, b),
+                    self.cache, cache)
         first = jnp.argmax(logits, -1).astype(jnp.int32)
         self.current = jnp.zeros((self.slots,), jnp.int32).at[:b].set(first)
         self.pos = jnp.zeros((self.slots,), jnp.int32).at[:b].set(s)
