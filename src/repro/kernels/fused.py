@@ -167,9 +167,11 @@ def fused_decode_attention(q, k_cache, v_cache, kv_pos, q_pos, *,
                            scale=None):
     """Grouped-einsum decode attention: GQA without materialising
     head-repeated K/V (the XLA-portable twin of the Pallas decode kernel).
-    q: (B, H, D); caches (B, C, Hkv, D); returns (B, H, D)."""
+    q: (B, H, D); caches (B, C, Hkv, W), W >= D, of which the first D
+    lanes are attended; returns (B, H, D)."""
     b, h, d = q.shape
     _, c, hkv, _ = k_cache.shape
+    k_cache, v_cache = k_cache[..., :d], v_cache[..., :d]
     rep = h // hkv
     scale_val = float(d ** -0.5) if scale is None else float(scale)
     qg = q.reshape(b, hkv, rep, d).astype(jnp.float32) * scale_val

@@ -67,9 +67,29 @@ def attention(q, k, v, *, causal=True, window=0, softcap=0.0, scale=None,
 
 
 def decode_attention(q, k_cache, v_cache, kv_pos, q_pos, *, window=0,
-                     softcap=0.0, scale=None, impl: Optional[str] = None):
-    """Single-token attention against a KV cache. See kernels.ref."""
+                     softcap=0.0, scale=None, layer=None,
+                     impl: Optional[str] = None):
+    """Single-token attention against a KV cache. See kernels.ref.
+
+    The caches' rows may be wider than q's head dim (zero-padded, see
+    ``models.layers.kv_row_width``); only the head dim is attended. With
+    ``layer`` (an int32 scalar) the caches are layer-stacked,
+    (L, B, C, Hkv, W) and (L, B, C), and the layer ``layer`` is attended:
+    the kernel reads it in place, the oracles index it."""
     mode = _resolve(impl)
+    if layer is not None and mode in ("pallas", "interp"):
+        from repro.kernels import decode_attention as da
+        return da.decode_attention_stacked(
+            q, k_cache, v_cache, kv_pos, q_pos, layer, window=window,
+            softcap=softcap, scale=scale, interpret=(mode == "interp"))
+    if layer is not None and mode == "ref":
+        return _ref.decode_attention_stacked(
+            q, k_cache, v_cache, kv_pos, q_pos, layer, window=window,
+            softcap=softcap, scale=scale)
+    if layer is not None:
+        k_cache, v_cache, kv_pos = (
+            _jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for a in (k_cache, v_cache, kv_pos))
     if mode == "ref":
         return _ref.decode_attention(q, k_cache, v_cache, kv_pos, q_pos,
                                      window=window, softcap=softcap,

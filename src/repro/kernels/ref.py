@@ -80,7 +80,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     Oracle for ``decode_attention``.
 
     q: (B, H, D) — one new token per sequence.
-    k_cache/v_cache: (B, C, Hkv, D) — C cache slots.
+    k_cache/v_cache: (B, C, Hkv, W), W >= D — C cache slots; only the
+        first D lanes of a row are attended (the rest is padding).
     kv_pos: (B, C) int32 — absolute position held in each slot; negative
         means the slot has never been written.
     q_pos: (B,) int32 — the query's absolute position.
@@ -88,8 +89,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """
     b, h, d = q.shape
     _, c, hkv, _ = k_cache.shape
-    k = _repeat_kv(k_cache, h // hkv)
-    v = _repeat_kv(v_cache, h // hkv)
+    k = _repeat_kv(k_cache[..., :d], h // hkv)
+    v = _repeat_kv(v_cache[..., :d], h // hkv)
     scale = (d ** -0.5) if scale is None else scale
     logits = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
@@ -101,6 +102,20 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhk,bkhd->bhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def decode_attention_stacked(q: jax.Array, k_cache: jax.Array,
+                             v_cache: jax.Array, kv_pos: jax.Array,
+                             q_pos: jax.Array, layer: jax.Array, *,
+                             window: int = 0, softcap: float = 0.0,
+                             scale: float | None = None) -> jax.Array:
+    """``decode_attention`` against layer ``layer`` (an int32 scalar) of
+    a layer-stacked cache: k_cache/v_cache (L, B, C, Hkv, W), kv_pos
+    (L, B, C). Oracle for ``decode_attention_stacked``."""
+    k, v, pos = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                 for a in (k_cache, v_cache, kv_pos))
+    return decode_attention(q, k, v, pos, q_pos, window=window,
+                            softcap=softcap, scale=scale)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,

@@ -137,9 +137,10 @@ def init_cache(cfg: ArchConfig, batch: int, enc_len: int) -> PyTree:
     dt = _dtype(cfg)
     L, T = cfg.n_layers, cfg.max_decoder_len
     hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    w = layers.kv_row_width(hd)
     return {
-        "self_k": jnp.zeros((L, batch, T, hkv, hd), dt),
-        "self_v": jnp.zeros((L, batch, T, hkv, hd), dt),
+        "self_k": jnp.zeros((L, batch, T, hkv, w), dt),
+        "self_v": jnp.zeros((L, batch, T, hkv, w), dt),
         "self_pos": jnp.full((L, batch, T), -1, jnp.int32),
         "cross_k": jnp.zeros((L, batch, enc_len, hkv, hd), dt),
         "cross_v": jnp.zeros((L, batch, enc_len, hkv, hd), dt),

@@ -161,6 +161,23 @@ def self_attention(params: dict, spec: AttnSpec, x: jax.Array,
     return _proj_out(out, params["wo"])
 
 
+def kv_row_width(head_dim: int) -> int:
+    """Lanes one head's row takes in a KV cache: ``head_dim`` rounded up
+    to whole 128-lane tiles, the pad zero and never attended.
+
+    The decode-attention kernel reads K/V row-major, and a TPU lays a
+    (..., C, Hkv, D) array out row-major only when D fills whole lane
+    tiles; for any other D it puts C minor-most, so a decode step would
+    relay the whole cache into the kernel's layout and back on every
+    step. Padded rows take the HBM a row-major unpadded cache takes."""
+    return -(-head_dim // 128) * 128
+
+
+def _pad_row(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad the last (head_dim) axis of ``x`` to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def self_attention_prefill(params: dict, spec: AttnSpec, x: jax.Array,
                            positions: jax.Array, cache_len: int):
     """Prefill: full attention + return the KV cache (ring-buffered to
@@ -174,32 +191,44 @@ def self_attention_prefill(params: dict, spec: AttnSpec, x: jax.Array,
 
     # scatter the (last cache_len) tokens into ring slots pos % cache_len
     slots = positions % cache_len                              # (b, s)
-    k_cache = jnp.zeros((b, cache_len, spec.n_kv_heads, spec.head_dim), k.dtype)
+    width = kv_row_width(spec.head_dim)
+    k_cache = jnp.zeros((b, cache_len, spec.n_kv_heads, width), k.dtype)
     v_cache = jnp.zeros_like(k_cache)
     kv_pos = jnp.full((b, cache_len), -1, jnp.int32)
     # keep only the newest writer per slot: scatter in increasing position
     # order (jnp scatter: later updates win; positions are sorted).
     bidx = jnp.arange(b)[:, None]
-    k_cache = k_cache.at[bidx, slots].set(k)
-    v_cache = v_cache.at[bidx, slots].set(v)
+    k_cache = k_cache.at[bidx, slots].set(_pad_row(k, width))
+    v_cache = v_cache.at[bidx, slots].set(_pad_row(v, width))
     kv_pos = kv_pos.at[bidx, slots].set(positions.astype(jnp.int32))
     return y, {"k": k_cache, "v": v_cache, "pos": kv_pos}
 
 
 def self_attention_decode(params: dict, spec: AttnSpec, x: jax.Array,
-                          cache: dict, q_pos: jax.Array):
-    """One-token decode. x: (B, 1, d); q_pos: (B,) absolute position."""
+                          cache: dict, q_pos: jax.Array,
+                          layer: Optional[jax.Array] = None):
+    """One-token decode. x: (B, 1, d); q_pos: (B,) absolute position.
+
+    ``cache`` holds one layer, k/v (B, C, Hkv, W) and pos (B, C); or,
+    with ``layer`` (an int32 scalar), the layer-stacked k/v
+    (L, B, C, Hkv, W) and pos (L, B, C), of which layer ``layer`` is
+    written and attended in place. W >= head_dim is the cache's row
+    width (``kv_row_width``)."""
     b = x.shape[0]
     q, k, v = _project_qkv(params, spec, x, q_pos[:, None])
-    cache_len = cache["k"].shape[1]
+    cache_len = cache["pos"].shape[-1]
+    width = cache["k"].shape[-1]
     slot = (q_pos % cache_len).astype(jnp.int32)               # (B,)
-    bidx = jnp.arange(b)
-    k_cache = cache["k"].at[bidx, slot].set(k[:, 0])
-    v_cache = cache["v"].at[bidx, slot].set(v[:, 0])
-    kv_pos = cache["pos"].at[bidx, slot].set(q_pos.astype(jnp.int32))
+    row = (jnp.arange(b), slot)
+    if layer is not None:
+        row = (layer,) + row
+    k_cache = cache["k"].at[row].set(_pad_row(k[:, 0], width))
+    v_cache = cache["v"].at[row].set(_pad_row(v[:, 0], width))
+    kv_pos = cache["pos"].at[row].set(q_pos.astype(jnp.int32))
     out = ops.decode_attention(q[:, 0], k_cache, v_cache, kv_pos,
                                q_pos.astype(jnp.int32), window=spec.window,
-                               softcap=spec.softcap, scale=spec.scale)
+                               softcap=spec.softcap, scale=spec.scale,
+                               layer=layer)
     y = _proj_out(out, params["wo"])[:, None, :]               # (B, 1, d)
     return y, {"k": k_cache, "v": v_cache, "pos": kv_pos}
 

@@ -191,9 +191,10 @@ def init_layer_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int):
     dt = _dtype(cfg)
     if kind in ("attn", "local"):
         c = cache_len_for(cfg, kind, max_len)
+        w = layers.kv_row_width(cfg.head_dim)
         return {
-            "k": jnp.zeros((batch, c, cfg.n_kv_heads, cfg.head_dim), dt),
-            "v": jnp.zeros((batch, c, cfg.n_kv_heads, cfg.head_dim), dt),
+            "k": jnp.zeros((batch, c, cfg.n_kv_heads, w), dt),
+            "v": jnp.zeros((batch, c, cfg.n_kv_heads, w), dt),
             "pos": jnp.full((batch, c), -1, jnp.int32),
         }
     if kind == "mamba2":
@@ -281,11 +282,14 @@ def prefill(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
 
 
 # ----------------------------------------------------------------- decode
-def _apply_layer_decode(p, cfg, kind, x, cache, q_pos):
+def _apply_layer_decode(p, cfg, kind, x, cache, q_pos, layer=None):
+    """``layer``: this attention layer's index in a layer-stacked
+    ``cache`` (see ``layers.self_attention_decode``); None for a cache of
+    its own."""
     if kind in ("attn", "local"):
         h = layers.apply_norm(cfg.norm, p["norm1"], x)
         y, cache = layers.self_attention_decode(
-            p["attn"], attn_spec(cfg, kind), h, cache, q_pos)
+            p["attn"], attn_spec(cfg, kind), h, cache, q_pos, layer)
         x = x + y
     else:
         h = layers.apply_norm(cfg.norm, p["norm1"], x)
@@ -317,25 +321,36 @@ def decode_step(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
         x = tokens.astype(_dtype(cfg))[:, None, :]
 
     def period_body(carry, scanned):
-        # The stacked cache rides in the CARRY with per-period
-        # dynamic_update_index, NOT as scan xs/ys: xs+ys would make the
-        # cache both a loop input and a separately-allocated output, which
-        # XLA cannot alias — it then copies the whole multi-GB KV stack
-        # every layer (measured 2x927 GB/step on nemotron decode_32k; see
-        # EXPERIMENTS §Perf iteration 'nemo-decode-2').
+        # The stacked cache rides in the CARRY, NOT as scan xs/ys: xs+ys
+        # would make the cache both a loop input and a separately-allocated
+        # output, which XLA cannot alias — it then copies the whole
+        # multi-GB KV stack every layer (measured 2x927 GB/step on
+        # nemotron decode_32k; see EXPERIMENTS §Perf iteration
+        # 'nemo-decode-2'). Attention layers write the new token's K/V row
+        # and position into the stack where it lies, and the kernel reads
+        # the layer from the stack: nothing layer-sized is sliced out or
+        # written back. With the cache donated at the jit boundary, as
+        # ServingEngine does, and its rows padded to whole lane tiles
+        # (layers.kv_row_width), the stack is not copied either.
+        # Recurrent states, O(d) a slot, are sliced out and written back.
         x, cache_all = carry
         x = constrain_batch(x)
         block, i = scanned
-        c_in = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
-            cache_all)
-        c_out = {}
+        cache_all = dict(cache_all)
         for j, kind in enumerate(cfg.layer_pattern):
-            x, c_out[f"layer{j}"] = _apply_layer_decode(
-                block[f"layer{j}"], cfg, kind, x, c_in[f"layer{j}"], pos)
-        cache_all = jax.tree.map(
-            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
-            cache_all, c_out)
+            name = f"layer{j}"
+            if kind in ("attn", "local"):
+                x, cache_all[name] = _apply_layer_decode(
+                    block[name], cfg, kind, x, cache_all[name], pos, layer=i)
+                continue
+            c = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                cache_all[name])
+            x, c = _apply_layer_decode(block[name], cfg, kind, x, c, pos)
+            cache_all[name] = jax.tree.map(
+                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+                cache_all[name], c)
         return (x, cache_all), None
 
     new_cache: dict = {}

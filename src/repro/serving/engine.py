@@ -38,6 +38,15 @@ def make_decode_fn(cfg: ArchConfig):
     return fn
 
 
+def jit_decode(cfg: ArchConfig):
+    """``make_decode_fn`` jitted as ``ServingEngine.step`` runs it, with
+    the cache (argument 2) donated: the step writes each new K/V row into
+    the buffers it was given, returns them as the new cache and copies
+    nothing cache-sized. The caller drops its reference to the old cache
+    (``step`` replaces it)."""
+    return jax.jit(make_decode_fn(cfg), donate_argnums=(2,))
+
+
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray          # (B, steps)
@@ -63,8 +72,8 @@ class ServingEngine:
         self.pos = jnp.zeros((slots,), jnp.int32)
         self.active = np.zeros((slots,), bool)
         self.current = jnp.zeros((slots,), jnp.int32)
-        self._decode = jax.jit(
-            lambda p, t, c, q: model.decode_step(p, self.cfg, t, c, q))
+        self._decode = jit_decode(cfg)
+        self._merge = jax.jit(_merge_cache, donate_argnums=(0,))
         self._prefill = jax.jit(
             lambda p, bb: model.prefill(p, self.cfg, bb))
 
@@ -128,9 +137,7 @@ class ServingEngine:
             if b == self.slots:
                 self.cache = cache
             else:
-                self.cache = jax.tree.map(
-                    lambda full, new: _merge_batch(full, new, b),
-                    self.cache, cache)
+                self.cache = self._merge(self.cache, cache)
         first = jnp.argmax(logits, -1).astype(jnp.int32)
         self.current = jnp.zeros((self.slots,), jnp.int32).at[:b].set(first)
         self.pos = jnp.zeros((self.slots,), jnp.int32).at[:b].set(s)
@@ -141,7 +148,13 @@ class ServingEngine:
         return GenerationResult(tokens=np.stack(out, axis=1), steps=steps)
 
 
-def _merge_batch(full: jax.Array, new: jax.Array, b: int) -> jax.Array:
+def _merge_cache(full: PyTree, new: PyTree) -> PyTree:
+    """Each leaf of the prefilled cache ``new`` written into the engine's
+    cache ``full`` (donated, so in place) at the leading corner."""
+    return jax.tree.map(_merge_batch, full, new)
+
+
+def _merge_batch(full: jax.Array, new: jax.Array) -> jax.Array:
     """Write `new` into `full` at the leading corner.
 
     A prefilled cache leaf can be smaller than the engine's along BOTH

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (decode_attention,
+                                            decode_attention_stacked)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.routing_decide import (routing_attain, routing_guard,
                                           routing_topk)
@@ -135,6 +136,42 @@ class TestDecodeAttention:
         q4 = q[:, None, :, :]
         out = ref.attention(q4, k, v, causal=True)
         np.testing.assert_allclose(got, out[:, 0], atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("layer", [0, 2, 4])
+    @pytest.mark.parametrize("h,hkv,window,q_pos,w", [
+        (4, 4, 0, [300, 517], 32),     # global; both rings wrapped (C=128)
+        (4, 4, 48, [300, 517], 32),    # sliding window over wrapped rings
+        (8, 2, 0, [90, 517], 32),      # GQA, rep 4; one ring not yet full
+        (8, 2, 48, [90, 517], 128),    # rows padded to a 128-lane tile
+    ])
+    def test_stacked_matches_ref_on_the_layer(self, layer, h, hkv, window,
+                                              q_pos, w):
+        """The stacked entry attends layer ``layer`` of an (L, B, C, Hkv,
+        W) stack as the oracle attends the first D lanes of that layer's
+        slab, and as the stacked oracle does. Layer l lags the query by l
+        positions, so a wrong layer's positions differ as well as its
+        keys and values."""
+        n_layers, b, d, c = 5, 2, 32, 128
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
+        k = jax.random.normal(ks[1], (n_layers, b, c, hkv, w), jnp.float32)
+        v = jax.random.normal(ks[2], (n_layers, b, c, hkv, w), jnp.float32)
+        qp = jnp.asarray(q_pos, jnp.int32)
+        # slot j holds the newest position = j (mod C) the layer has
+        # written, -1 where it has written none
+        newest = qp[None, :, None] - jnp.arange(n_layers)[:, None, None]
+        kv_pos = newest - (newest - jnp.arange(c)) % c
+        kv_pos = jnp.where(kv_pos >= 0, kv_pos, -1).astype(jnp.int32)
+        got = decode_attention_stacked(q, k, v, kv_pos, qp,
+                                       jnp.int32(layer), window=window,
+                                       block_kv=64, interpret=True)
+        want = ref.decode_attention(q, k[layer, ..., :d], v[layer, ..., :d],
+                                    kv_pos[layer], qp, window=window)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(
+            ref.decode_attention_stacked(q, k, v, kv_pos, qp,
+                                         jnp.int32(layer), window=window),
+            want)
 
 
 class TestSSDScan:

@@ -1,10 +1,15 @@
-"""Serving engine: slot batching, generation consistency."""
+"""Serving engine: slot batching, generation consistency, and the
+decode step's in-place cache update."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.base import get_config, reduced
-from repro.models import model
+from repro.kernels import ops
+from repro.models import model, transformer
 from repro.serving.engine import ServingEngine
 
 
@@ -44,6 +49,17 @@ class TestServingEngine:
         assert eng.free_slots() == [0, 2, 3]
         eng.release(1)
         assert eng.free_slots() == [0, 1, 2, 3]
+
+    def test_step_donates_the_cache(self):
+        """The decode step takes the cache's buffers for its output: the
+        engine's previous cache is consumed, not kept beside a copy."""
+        cfg, params = setup()
+        eng = ServingEngine(cfg, params, slots=2, max_len=32)
+        eng.generate(jnp.ones((2, 8), jnp.int32), steps=2)
+        before = jax.tree.leaves(eng.cache)
+        eng.step()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in jax.tree.leaves(eng.cache))
 
     def test_decode_steps_advance_positions(self):
         cfg, params = setup()
@@ -85,3 +101,75 @@ class TestPartialBatchMerge:
         eight = ServingEngine(cfg, params, slots=8, max_len=64) \
             .generate(prompts, steps=4)
         np.testing.assert_array_equal(four.tokens, eight.tokens)
+
+
+def _slice_and_write_back_step(params, cfg, tokens, cache, pos):
+    """The decode step with every layer's cache sliced out of the stack,
+    updated on its own and written back whole: the reference for the
+    step that writes attention rows into the stack in place."""
+    x = params["embed"][tokens][:, None, :]
+    new: dict = {}
+    if cfg.n_periods > 0:
+        stack = cache["blocks"]
+        for i in range(cfg.n_periods):
+            for j, kind in enumerate(cfg.layer_pattern):
+                name = f"layer{j}"
+                p = jax.tree.map(lambda a: a[i], params["blocks"][name])
+                c = jax.tree.map(lambda a: a[i], stack[name])
+                x, c = transformer._apply_layer_decode(p, cfg, kind, x, c,
+                                                       pos)
+                stack = {**stack, name: jax.tree.map(
+                    lambda a, u: a.at[i].set(u), stack[name], c)}
+        new["blocks"] = stack
+    if cfg.n_remainder_layers:
+        new["remainder"] = []
+        for j, p in enumerate(params["remainder"]):
+            x, c = transformer._apply_layer_decode(
+                p, cfg, cfg.layer_pattern[j], x, cache["remainder"][j], pos)
+            new["remainder"].append(c)
+    return transformer._logits(params, cfg, x)[:, 0, :], new
+
+
+# (architecture, layers, kv heads, kernel path): global attention whose
+# ring wraps; local + global with a local remainder layer; rglru + local
+# with two unrolled rglru remainder layers; phi3's 4 query heads per kv
+# head; the Pallas stacked kernel (interpret mode) inside the step; and
+# the grouped-einsum twin
+PARITY = [
+    ("stablelm_3b", 2, None, "ref"),
+    ("gemma2_27b", 5, None, "ref"),
+    ("recurrentgemma_2b", 8, None, "ref"),
+    ("phi3_medium_14b", 2, 1, "ref"),
+    ("stablelm_3b", 2, None, "interp"),
+    ("gemma2_27b", 5, None, "interp"),
+    ("phi3_medium_14b", 2, 1, "fused"),
+]
+
+
+@pytest.mark.parametrize("arch,n_layers,n_kv,impl", PARITY)
+def test_in_place_decode_matches_slice_and_write_back(arch, n_layers, n_kv,
+                                                       impl, monkeypatch):
+    monkeypatch.setattr(ops, "_IMPL", impl)
+    cfg = dataclasses.replace(reduced(get_config(arch)), n_layers=n_layers)
+    if n_kv is not None:
+        cfg = dataclasses.replace(cfg, n_kv_heads=n_kv)
+    b, s, max_len, steps = 3, 8, 16, 10
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    keys = jax.random.split(jax.random.PRNGKey(1), steps + 1)
+    prompts = jax.random.randint(keys[0], (b, s), 0, cfg.vocab_size)
+    _, cache = transformer.prefill(params, cfg, prompts, max_len=max_len)
+    want_cache = cache
+    # slots at different positions; the last passes max_len and wraps
+    pos = s + 3 * jnp.arange(b, dtype=jnp.int32)
+    step = jax.jit(lambda p, t, c, q: model.decode_step(p, cfg, t, c, q))
+    ref_step = jax.jit(lambda p, t, c, q: _slice_and_write_back_step(
+        p, cfg, t, c, q))
+    for k in keys[1:]:
+        tok = jax.random.randint(k, (b,), 0, cfg.vocab_size)
+        logits, cache = step(params, tok, cache, pos)
+        want, want_cache = ref_step(params, tok, want_cache, pos)
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+        jax.tree.map(lambda g, w: np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w)), cache, want_cache)
+        pos = pos + 1
+    assert int(pos[-1]) > max_len
