@@ -17,8 +17,23 @@ from typing import Optional
 ARCH_IDS = [
     "chameleon_34b", "mamba2_370m", "recurrentgemma_2b", "nemotron_4_340b",
     "gemma2_27b", "dbrx_132b", "stablelm_3b", "arctic_480b",
-    "whisper_small", "phi3_medium_14b",
+    "whisper_small", "phi3_medium_14b", "mellum2_12b",
 ]
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071), as transformers'
+    ``_compute_yarn_parameters`` reads a ``rope_type: yarn`` block: each
+    frequency blends the plain one with it divided by ``factor``, over a
+    linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations in ``original_max_positions``; cos and sin
+    are scaled by ``attention_factor``."""
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +78,7 @@ class ArchConfig:
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     rope_theta: float = 10000.0
+    global_yarn: Optional[Yarn] = None   # YaRN on the "attn" layers only
     use_rope: bool = True
     qk_norm: bool = False
     attn_scale: Optional[float] = None

@@ -105,6 +105,21 @@ def decode_attention(q, k_cache, v_cache, kv_pos, q_pos, *, window=0,
                                interpret=(mode == "interp"))
 
 
+def moe_gmm(x, w, group_sizes, layer=None, impl: Optional[str] = None):
+    """Grouped matmul of expert-sorted rows, (M, K) x (E, K, N) ->
+    (M, N). See kernels.ref.moe_gmm. With ``layer`` (an int32 scalar)
+    w is a layer stack (L, E, K, N) and layer ``layer`` is used: the
+    kernel reads it in place, the oracle indexes it."""
+    mode = _resolve(impl)
+    if mode in ("ref", "fused"):
+        if layer is not None:
+            w = _jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        return _ref.moe_gmm(x, w, group_sizes)
+    from repro.kernels import moe_gmm as gmm
+    return gmm.moe_gmm(x, w, group_sizes, layer,
+                       interpret=(mode == "interp"))
+
+
 def ssd_scan(x, dt, a, b, c, d_skip, initial_state=None,
              return_final_state=False, impl: Optional[str] = None,
              chunk: int = 64):

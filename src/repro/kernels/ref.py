@@ -118,6 +118,29 @@ def decode_attention_stacked(q: jax.Array, k_cache: jax.Array,
                             softcap=softcap, scale=scale)
 
 
+def moe_gmm(x: jax.Array, w: jax.Array,
+            group_sizes: jax.Array) -> jax.Array:
+    """Grouped matmul: the rows of x (M, K) are sorted by group, the
+    first ``group_sizes[0]`` belong to group 0 and so on; row r of the
+    result is ``x[r] @ w[g(r)]`` for w (E, K, N), f32 accumulation, in
+    x's dtype. Rows past ``sum(group_sizes)`` are zero. Oracle for
+    ``moe_gmm``: every group's product over all rows, each row kept
+    from its own group's."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    rows = jnp.arange(x.shape[0])
+
+    def one(acc, scanned):
+        start, end, we = scanned
+        y = jnp.dot(x, we, preferred_element_type=jnp.float32)
+        mine = (rows >= start) & (rows < end)
+        return jnp.where(mine[:, None], y, acc), None
+
+    acc = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    acc, _ = jax.lax.scan(one, acc, (starts, ends, w))
+    return acc.astype(x.dtype)
+
+
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, d_skip: jax.Array,
              initial_state: jax.Array | None = None,

@@ -80,12 +80,16 @@ def step_fn_for(cfg: ArchConfig, shape: InputShape):
         args = (params_specs(cfg), opt_state_specs(cfg),
                 train_batch_specs(cfg, shape))
         return fn, args
+    # the serving shapes lower the capacity MoE: GSPMD shards its expert
+    # einsum over the model axis, and a Mosaic kernel (the dropless
+    # path's moe_gmm) cannot be partitioned
     if shape.kind == "prefill":
-        fn = lambda params, batch: model.prefill(params, cfg, batch)
+        fn = lambda params, batch: model.prefill(params, cfg, batch,
+                                                 moe_dropless=False)
         return fn, (params_specs(cfg), prefill_batch_specs(cfg, shape))
     # decode: one new token against a seq_len-deep cache
     fn = lambda params, tokens, cache, pos: model.decode_step(
-        params, cfg, tokens, cache, pos)
+        params, cfg, tokens, cache, pos, moe_dropless=False)
     tokens, pos = decode_token_specs(cfg, shape)
     cache = cache_specs(cfg, shape.global_batch, shape.seq_len)
     return fn, (params_specs(cfg), tokens, cache, pos)

@@ -15,7 +15,8 @@ Design notes
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -70,14 +71,44 @@ def apply_norm(kind: str, params: dict, x: jax.Array) -> jax.Array:
 
 
 # ------------------------------------------------------------------ rope
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Array:
+def rope_frequencies(d: int, theta: float, yarn=None):
+    """(d/2,) rotation frequencies theta^(-2i/d) and the factor cos and
+    sin are scaled by. With ``yarn`` (``configs.base.Yarn``) each
+    frequency is blended with itself over ``yarn.factor``: the plain one
+    below the correction dim of ``beta_fast`` rotations, the divided one
+    above that of ``beta_slow``, a linear ramp between (transformers'
+    ``_compute_yarn_parameters``, ``truncate`` on)."""
+    half = d // 2
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        return freq, 1.0
+
+    def dim_of(rotations):
+        return (d * math.log(yarn.original_max_positions
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    freq = freq / yarn.factor * ramp + freq * (1.0 - ramp)
+    return freq, yarn.attention_factor
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         yarn=None) -> jax.Array:
     """Rotary embedding. x: (..., S, H, D), positions: (..., S)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq, mscale = rope_frequencies(d, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freq       # (..., S, half)
     angles = angles[..., None, :]                                  # (..., S, 1, half)
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if mscale != 1.0:
+        sin, cos = sin * mscale, cos * mscale
     x1, x2 = x[..., :half], x[..., half:]
     y1 = x1.astype(jnp.float32) * cos - x2.astype(jnp.float32) * sin
     y2 = x2.astype(jnp.float32) * cos + x1.astype(jnp.float32) * sin
@@ -106,6 +137,7 @@ class AttnSpec:
     use_rope: bool = True
     qk_norm: bool = False    # chameleon-style query/key RMSNorm
     scale: Optional[float] = None
+    yarn: Any = None         # configs.base.Yarn: YaRN-scaled rotary
 
 
 def attention_init(key, spec: AttnSpec, dtype) -> dict:
@@ -146,8 +178,8 @@ def _project_qkv(params, spec: AttnSpec, x, positions):
         q = rmsnorm(params["q_norm"], q)
         k = rmsnorm(params["k_norm"], k)
     if spec.use_rope:
-        q = rope(q, positions, spec.rope_theta)
-        k = rope(k, positions, spec.rope_theta)
+        q = rope(q, positions, spec.rope_theta, spec.yarn)
+        k = rope(k, positions, spec.rope_theta, spec.yarn)
     return q, k, v
 
 
@@ -181,7 +213,8 @@ def _pad_row(x: jax.Array, width: int) -> jax.Array:
 def self_attention_prefill(params: dict, spec: AttnSpec, x: jax.Array,
                            positions: jax.Array, cache_len: int):
     """Prefill: full attention + return the KV cache (ring-buffered to
-    cache_len slots, newest tokens win)."""
+    cache_len slots, newest tokens win). ``positions`` rise by one along
+    the sequence, as prefill's do."""
     q, k, v = _project_qkv(params, spec, x, positions)
     out = ops.attention(q, k, v, causal=spec.causal, window=spec.window,
                         softcap=spec.softcap, scale=spec.scale,
@@ -189,14 +222,16 @@ def self_attention_prefill(params: dict, spec: AttnSpec, x: jax.Array,
     b, s = out.shape[:2]
     y = _proj_out(out, params["wo"])
 
-    # scatter the (last cache_len) tokens into ring slots pos % cache_len
-    slots = positions % cache_len                              # (b, s)
+    # scatter the last cache_len tokens into ring slots pos % cache_len:
+    # only they survive, and each takes a slot of its own (a scatter with
+    # two writers to one slot keeps either on a TPU)
+    if s > cache_len:
+        k, v, positions = (a[:, s - cache_len:] for a in (k, v, positions))
+    slots = positions % cache_len
     width = kv_row_width(spec.head_dim)
     k_cache = jnp.zeros((b, cache_len, spec.n_kv_heads, width), k.dtype)
     v_cache = jnp.zeros_like(k_cache)
     kv_pos = jnp.full((b, cache_len), -1, jnp.int32)
-    # keep only the newest writer per slot: scatter in increasing position
-    # order (jnp scatter: later updates win; positions are sorted).
     bidx = jnp.arange(b)[:, None]
     k_cache = k_cache.at[bidx, slots].set(_pad_row(k, width))
     v_cache = v_cache.at[bidx, slots].set(_pad_row(v, width))
@@ -297,10 +332,80 @@ def moe_init(key, d: int, d_ff: int, n_experts: int, kind: str, dtype) -> dict:
     return p
 
 
+def _expert_hidden(g: jax.Array, h: jax.Array, kind: str) -> jax.Array:
+    """The experts' activation of the up projection ``h`` (and, gated,
+    of the gate projection ``g``), in f32, cast back to h's dtype."""
+    if kind == "swiglu":
+        return (jax.nn.silu(g.astype(jnp.float32))
+                * h.astype(jnp.float32)).astype(h.dtype)
+    if kind == "geglu":
+        return (jax.nn.gelu(g.astype(jnp.float32), approximate=True)
+                * h.astype(jnp.float32)).astype(h.dtype)
+    if kind == "relu2":
+        return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(h.dtype)
+    if kind == "gelu":
+        return jax.nn.gelu(h.astype(jnp.float32),
+                           approximate=True).astype(h.dtype)
+    raise ValueError(f"unknown mlp kind {kind}")
+
+
+def moe_dropless(params: dict, x: jax.Array, *, top_k: int, kind: str,
+                 layer: Optional[jax.Array] = None
+                 ) -> tuple[jax.Array, jax.Array]:
+    """Dropless top-k MoE, the serving path: every token reaches all of
+    its ``top_k`` experts, however unevenly the router spreads them.
+
+    The router runs in f32 at full precision: softmax over the experts,
+    top-k, the k gates renormalised to sum to 1. The tokens' T*k rows
+    are sorted by expert and go through ``ops.moe_gmm`` for the up (and
+    gate) and the down projection, which reads only the experts that
+    received rows; each token's output is the gate-weighted sum of its k
+    rows, in f32.
+
+    x: (B, S, d). With ``layer`` (an int32 scalar) ``params`` holds a
+    stack of layers' MoE weights and layer ``layer``'s are used, the
+    expert matrices read where they lie (``ops.moe_gmm``). Returns
+    (output, stats): stats is int32 (2,), the experts that received at
+    least one row and the rows routed (T*k, read back from the group
+    sizes the kernel was given)."""
+    b, s, d = x.shape
+    e = params["router"].shape[-1]
+    router = params["router"]
+    if layer is not None:
+        router = jax.lax.dynamic_index_in_dim(router, layer, 0,
+                                              keepdims=False)
+    xf = x.reshape(b * s, d)
+    logits = jnp.dot(xf.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, expert = jax.lax.top_k(probs, top_k)                   # (T, k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+
+    flat = expert.reshape(-1)                                    # (T*k,)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    rows = xf[order // top_k]                                    # sorted
+    h = ops.moe_gmm(rows, params["wi"], sizes, layer)
+    g = (ops.moe_gmm(rows, params["wg"], sizes, layer) if "wg" in params
+         else None)
+    out = ops.moe_gmm(_expert_hidden(g, h, kind), params["wo"], sizes, layer)
+
+    # back to token order (row j of the (T, k) layout sits at rank[j]),
+    # weighted and summed over k in f32: one fusion, no f32 copy of rows
+    rank = jnp.argsort(order)
+    y = jnp.sum(out[rank].reshape(b * s, top_k, d).astype(jnp.float32)
+                * gate[..., None], axis=1)
+    stats = jnp.stack([jnp.sum(sizes > 0), jnp.sum(sizes)]).astype(jnp.int32)
+    return y.astype(x.dtype).reshape(b, s, d), stats
+
+
 def moe(params: dict, x: jax.Array, *, top_k: int, kind: str,
         capacity_factor: float = 1.25) -> tuple[jax.Array, jax.Array]:
-    """Dropless-ish top-k MoE: data-local grouped dispatch + expert-parallel
-    FFN (sort-based, gather/scatter kept *within* a token group).
+    """Capacity top-k MoE, the training and dry-run path: data-local
+    grouped dispatch + expert-parallel FFN (sort-based, gather/scatter
+    kept *within* a token group). Each expert takes at most
+    ``round(Tg * top_k / E * capacity_factor)`` rows of a group; the
+    rest are dropped (serving uses ``moe_dropless``).
 
     x: (B, S, d). Returns (output, aux_loss) with the Switch-style
     load-balance loss. Tokens are split into ``dist.moe_num_groups()``
@@ -362,20 +467,12 @@ def moe(params: dict, x: jax.Array, *, top_k: int, kind: str,
     wi = dist.constrain_moe_weight(params["wi"])
     h = jnp.einsum("gecd,edf->gecf", buf, wi,
                    preferred_element_type=jnp.float32).astype(x.dtype)
-    if kind == "swiglu":
+    g = None
+    if kind in ("swiglu", "geglu"):
         g = jnp.einsum("gecd,edf->gecf", buf,
                        dist.constrain_moe_weight(params["wg"]),
                        preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * h.astype(jnp.float32)).astype(x.dtype)
-    elif kind == "geglu":
-        g = jnp.einsum("gecd,edf->gecf", buf,
-                       dist.constrain_moe_weight(params["wg"]),
-                       preferred_element_type=jnp.float32)
-        h = (jax.nn.gelu(g, approximate=True) * h.astype(jnp.float32)).astype(x.dtype)
-    elif kind == "relu2":
-        h = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(x.dtype)
-    elif kind == "gelu":
-        h = jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(x.dtype)
+    h = _expert_hidden(g, h, kind)
     out_e = jnp.einsum("gecf,efd->gecd", h,
                        dist.constrain_moe_weight(params["wo"]),
                        preferred_element_type=jnp.float32).astype(x.dtype)

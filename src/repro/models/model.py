@@ -8,7 +8,7 @@ Batch conventions (match launch.input_specs):
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,12 +33,14 @@ def forward(params: PyTree, cfg: ArchConfig, batch: dict):
     return transformer.forward(params, cfg, inp)
 
 
-def prefill(params: PyTree, cfg: ArchConfig, batch: dict):
-    """-> (last-token fp32 logits (B, V), cache)."""
+def prefill(params: PyTree, cfg: ArchConfig, batch: dict, *,
+            moe_dropless: bool = True):
+    """-> (last-token fp32 logits (B, V), cache). ``moe_dropless``: see
+    ``transformer.prefill``."""
     if cfg.is_encoder_decoder:
         return encdec.prefill(params, cfg, batch["frames"], batch["tokens"])
     inp = batch.get("tokens", batch.get("embeddings"))
-    return transformer.prefill(params, cfg, inp)
+    return transformer.prefill(params, cfg, inp, moe_dropless=moe_dropless)
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
@@ -47,12 +49,24 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
     return transformer.init_cache(cfg, batch, max_len)
 
 
+def init_moe_counts(cfg: ArchConfig):
+    """Zeroed decode-step MoE counters, None without experts (see
+    ``transformer.init_moe_counts``)."""
+    if cfg.is_encoder_decoder:
+        return None
+    return transformer.init_moe_counts(cfg)
+
+
 def decode_step(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
-                cache: PyTree, pos: jax.Array):
-    """-> ((B, V) fp32 logits, new cache)."""
+                cache: PyTree, pos: jax.Array, *, moe_dropless: bool = True,
+                moe_counts: Optional[jax.Array] = None):
+    """-> ((B, V) fp32 logits, new cache), and the MoE counters when
+    given ``moe_counts``; see ``transformer.decode_step``."""
     if cfg.is_encoder_decoder:
         return encdec.decode_step(params, cfg, tokens, cache, pos)
-    return transformer.decode_step(params, cfg, tokens, cache, pos)
+    return transformer.decode_step(params, cfg, tokens, cache, pos,
+                                   moe_dropless=moe_dropless,
+                                   moe_counts=moe_counts)
 
 
 # ------------------------------------------------------------- accounting

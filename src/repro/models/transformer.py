@@ -40,7 +40,8 @@ def attn_spec(cfg: ArchConfig, kind: str) -> layers.AttnSpec:
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, rope_theta=cfg.rope_theta, window=window,
         softcap=cfg.attn_softcap, causal=True, use_rope=cfg.use_rope,
-        qk_norm=cfg.qk_norm, scale=cfg.attn_scale)
+        qk_norm=cfg.qk_norm, scale=cfg.attn_scale,
+        yarn=cfg.global_yarn if kind == "attn" else None)
 
 
 def cache_len_for(cfg: ArchConfig, kind: str, max_len: int) -> int:
@@ -54,6 +55,23 @@ def cache_len_for(cfg: ArchConfig, kind: str, max_len: int) -> int:
 def _has_mlp(cfg: ArchConfig, kind: str) -> bool:
     # Mamba-2 blocks are the whole layer; attention/rglru layers carry an MLP.
     return cfg.d_ff > 0 and kind != "mamba2"
+
+
+def _layer_kinds(cfg: ArchConfig) -> list:
+    """Every layer's kind in order: the scanned periods, the remainder."""
+    return (list(cfg.layer_pattern) * cfg.n_periods
+            + list(cfg.layer_pattern[: cfg.n_remainder_layers]))
+
+
+def init_moe_counts(cfg: ArchConfig) -> Optional[jax.Array]:
+    """Zeroed decode-step MoE counters, int32 (MoE layers, 2): per MoE
+    layer, in layer order, the experts that received a row and the rows
+    routed, each summed over decode steps (see ``decode_step``). None
+    for a model without experts."""
+    if cfg.n_experts == 0:
+        return None
+    n = sum(_has_mlp(cfg, kind) for kind in _layer_kinds(cfg))
+    return jnp.zeros((n, 2), jnp.int32)
 
 
 # ------------------------------------------------------------------ init
@@ -114,30 +132,67 @@ def init_params(key, cfg: ArchConfig) -> PyTree:
 
 
 # --------------------------------------------------------------- forward
+def _mlp_block(p: dict, cfg: ArchConfig, kind: str, x: jax.Array,
+               dropless: bool, expert_layer: Optional[jax.Array] = None):
+    """The layer's second half, pre-norm residual: x plus the MLP, or the
+    MoE (with the dense residual MLP beside it, Arctic), of norm2(x).
+    ``dropless`` picks the serving MoE (``layers.moe_dropless``) over
+    the capacity one that training and the dry run lower;
+    ``expert_layer``: this layer's index in the period-stacked MoE
+    weights ``p["moe"]`` holds (see ``_split_experts``). Returns (x,
+    the capacity MoE's load-balance loss or None, the dropless MoE's
+    stats or None)."""
+    if not _has_mlp(cfg, kind):
+        return x, None, None
+    h = layers.apply_norm(cfg.norm, p["norm2"], x)
+    if cfg.n_experts == 0:
+        return x + layers.mlp(p["mlp"], h, cfg.mlp_kind), None, None
+    aux = stats = None
+    if dropless:
+        y, stats = layers.moe_dropless(p["moe"], h, top_k=cfg.top_k,
+                                       kind=cfg.mlp_kind, layer=expert_layer)
+    else:
+        y, aux = layers.moe(p["moe"], h, top_k=cfg.top_k, kind=cfg.mlp_kind,
+                            capacity_factor=cfg.capacity_factor)
+    if cfg.dense_residual:
+        y = y + layers.mlp(p["dense_mlp"], h, cfg.mlp_kind)
+    return x + y, aux, stats
+
+
+def _split_experts(blocks: dict, cfg: ArchConfig, dropless: bool):
+    """For a scan over periods with the dropless MoE: (the period blocks
+    without their MoE weights, for the scan to slice, and each layer's
+    MoE weights stacked over periods, which ``moe_gmm`` reads in place
+    at the period's index). Sliced by the scan, every expert of a layer
+    would be copied out of the stack each step, touched or not: a
+    Mosaic kernel's operand is a buffer of its own."""
+    if not (dropless and cfg.n_experts):
+        return blocks, {}
+    scanned = {name: {k: v for k, v in blk.items() if k != "moe"}
+               for name, blk in blocks.items()}
+    return scanned, {name: blk["moe"] for name, blk in blocks.items()
+                     if "moe" in blk}
+
+
+def _with_experts(block: dict, experts: dict, name: str) -> dict:
+    return dict(block, moe=experts[name]) if name in experts else block
+
+
 def _apply_layer(p: dict, cfg: ArchConfig, kind: str, x: jax.Array,
                  positions: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Pre-norm residual layer. Returns (x, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Pre-norm residual layer, the training path (capacity MoE).
+    Returns (x, aux_loss)."""
+    zero = jnp.zeros((), jnp.float32)
     h = layers.apply_norm(cfg.norm, p["norm1"], x)
     if kind in ("attn", "local"):
         x = x + layers.self_attention(p["attn"], attn_spec(cfg, kind), h,
                                       positions)
     elif kind == "mamba2":
-        return x + ssm.forward(p["mixer"], cfg, h), aux
+        x = x + ssm.forward(p["mixer"], cfg, h)
     elif kind == "rglru":
         x = x + rglru.forward(p["mixer"], cfg, h)
-    if _has_mlp(cfg, kind):
-        h2 = layers.apply_norm(cfg.norm, p["norm2"], x)
-        if cfg.n_experts > 0:
-            y, aux = layers.moe(p["moe"], h2, top_k=cfg.top_k,
-                                kind=cfg.mlp_kind,
-                                capacity_factor=cfg.capacity_factor)
-            if cfg.dense_residual:
-                y = y + layers.mlp(p["dense_mlp"], h2, cfg.mlp_kind)
-            x = x + y
-        else:
-            x = x + layers.mlp(p["mlp"], h2, cfg.mlp_kind)
-    return x, aux
+    x, aux, _ = _mlp_block(p, cfg, kind, x, dropless=False)
+    return x, zero if aux is None else aux
 
 
 def _embed(params, cfg: ArchConfig, tokens_or_embeddings: jax.Array):
@@ -221,100 +276,93 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> PyTree:
 
 
 # ---------------------------------------------------------------- prefill
-def _apply_layer_prefill(p, cfg, kind, x, positions, max_len):
+def _apply_layer_prefill(p, cfg, kind, x, positions, max_len,
+                         dropless=True, expert_layer=None):
+    h = layers.apply_norm(cfg.norm, p["norm1"], x)
     if kind in ("attn", "local"):
-        h = layers.apply_norm(cfg.norm, p["norm1"], x)
         y, cache = layers.self_attention_prefill(
             p["attn"], attn_spec(cfg, kind), h, positions,
             cache_len_for(cfg, kind, max_len))
-        x = x + y
     else:
-        h = layers.apply_norm(cfg.norm, p["norm1"], x)
         mod = ssm if kind == "mamba2" else rglru
         y, cache = mod.forward(p["mixer"], cfg, h, return_state=True)
-        x = x + y
-        if kind == "mamba2":
-            return x, cache
-    if _has_mlp(cfg, kind):
-        h2 = layers.apply_norm(cfg.norm, p["norm2"], x)
-        if cfg.n_experts > 0:
-            y, _ = layers.moe(p["moe"], h2, top_k=cfg.top_k, kind=cfg.mlp_kind,
-                              capacity_factor=cfg.capacity_factor)
-            if cfg.dense_residual:
-                y = y + layers.mlp(p["dense_mlp"], h2, cfg.mlp_kind)
-            x = x + y
-        else:
-            x = x + layers.mlp(p["mlp"], h2, cfg.mlp_kind)
+    x, _, _ = _mlp_block(p, cfg, kind, x + y, dropless, expert_layer)
     return x, cache
 
 
 def prefill(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
-            max_len: Optional[int] = None) -> tuple[jax.Array, PyTree]:
-    """Prefill pass: returns (last-token fp32 logits (B, V), cache)."""
+            max_len: Optional[int] = None, *,
+            moe_dropless: bool = True) -> tuple[jax.Array, PyTree]:
+    """Prefill pass: returns (last-token fp32 logits (B, V), cache).
+    ``moe_dropless=False`` lowers the capacity MoE instead (the dry
+    run)."""
     x = constrain_batch(_embed(params, cfg, tokens))
     b, s = x.shape[:2]
     max_len = max_len or s
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
 
-    def period_body(x, block):
+    def period_body(x, scanned):
+        block, i = scanned
         x = constrain_batch(x)
         caches = {}
         for j, kind in enumerate(cfg.layer_pattern):
-            x, caches[f"layer{j}"] = _apply_layer_prefill(
-                block[f"layer{j}"], cfg, kind, x, positions, max_len)
+            name = f"layer{j}"
+            x, caches[name] = _apply_layer_prefill(
+                _with_experts(block[name], experts, name), cfg, kind, x,
+                positions, max_len, moe_dropless,
+                i if name in experts else None)
         return x, caches
 
     cache: dict = {}
     if cfg.n_periods > 0:
+        blocks, experts = _split_experts(params["blocks"], cfg, moe_dropless)
         body = period_body
         if cfg.remat:
             body = jax.checkpoint(period_body,
                                   policy=jax.checkpoint_policies.nothing_saveable)
-        x, cache["blocks"] = jax.lax.scan(body, x, params["blocks"])
+        x, cache["blocks"] = jax.lax.scan(
+            body, x, (blocks, jnp.arange(cfg.n_periods) if experts else None))
     rem = cfg.layer_pattern[: cfg.n_remainder_layers]
     if rem:
         cache["remainder"] = []
         for j, p in enumerate(params["remainder"]):
-            x, c = _apply_layer_prefill(p, cfg, rem[j], x, positions, max_len)
+            x, c = _apply_layer_prefill(p, cfg, rem[j], x, positions,
+                                        max_len, moe_dropless)
             cache["remainder"].append(c)
     logits = _logits(params, cfg, x[:, -1:, :])[:, 0, :]
     return logits, cache
 
 
 # ----------------------------------------------------------------- decode
-def _apply_layer_decode(p, cfg, kind, x, cache, q_pos, layer=None):
+def _apply_layer_decode(p, cfg, kind, x, cache, q_pos, layer=None,
+                        dropless=True, expert_layer=None):
     """``layer``: this attention layer's index in a layer-stacked
     ``cache`` (see ``layers.self_attention_decode``); None for a cache of
-    its own."""
+    its own. ``expert_layer``: see ``_mlp_block``. Returns (x, cache,
+    the dropless MoE's stats or None)."""
+    h = layers.apply_norm(cfg.norm, p["norm1"], x)
     if kind in ("attn", "local"):
-        h = layers.apply_norm(cfg.norm, p["norm1"], x)
         y, cache = layers.self_attention_decode(
             p["attn"], attn_spec(cfg, kind), h, cache, q_pos, layer)
-        x = x + y
     else:
-        h = layers.apply_norm(cfg.norm, p["norm1"], x)
         mod = ssm if kind == "mamba2" else rglru
         y, cache = mod.decode_step(p["mixer"], cfg, h, cache)
-        x = x + y
-        if kind == "mamba2":
-            return x, cache
-    if _has_mlp(cfg, kind):
-        h2 = layers.apply_norm(cfg.norm, p["norm2"], x)
-        if cfg.n_experts > 0:
-            y, _ = layers.moe(p["moe"], h2, top_k=cfg.top_k, kind=cfg.mlp_kind,
-                              capacity_factor=cfg.capacity_factor)
-            if cfg.dense_residual:
-                y = y + layers.mlp(p["dense_mlp"], h2, cfg.mlp_kind)
-            x = x + y
-        else:
-            x = x + layers.mlp(p["mlp"], h2, cfg.mlp_kind)
-    return x, cache
+    x, _, stats = _mlp_block(p, cfg, kind, x + y, dropless, expert_layer)
+    return x, cache, stats
 
 
 def decode_step(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
-                cache: PyTree, pos: jax.Array) -> tuple[jax.Array, PyTree]:
+                cache: PyTree, pos: jax.Array, *, moe_dropless: bool = True,
+                moe_counts: Optional[jax.Array] = None):
     """One decode step. tokens: (B,) int32 (or (B, d) embeddings);
-    pos: (B,) absolute positions. Returns ((B, V) fp32 logits, new cache)."""
+    pos: (B,) absolute positions. Returns ((B, V) fp32 logits, new cache).
+
+    ``moe_dropless=False`` lowers the capacity MoE instead (the dry
+    run). With ``moe_counts`` (``init_moe_counts``; dropless MoE only)
+    the step also returns them with this step's stats added: per MoE
+    layer, the experts that received at least one of the B*top_k rows,
+    and the rows routed."""
+    count = moe_counts is not None
     if tokens.ndim == 1 and cfg.frontend == "tokens":
         x = params["embed"][tokens][:, None, :]
     else:
@@ -337,33 +385,50 @@ def decode_step(params: PyTree, cfg: ArchConfig, tokens: jax.Array,
         x = constrain_batch(x)
         block, i = scanned
         cache_all = dict(cache_all)
+        stats = []
         for j, kind in enumerate(cfg.layer_pattern):
             name = f"layer{j}"
+            p = _with_experts(block[name], experts, name)
+            at = i if name in experts else None
             if kind in ("attn", "local"):
-                x, cache_all[name] = _apply_layer_decode(
-                    block[name], cfg, kind, x, cache_all[name], pos, layer=i)
-                continue
-            c = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                       keepdims=False),
-                cache_all[name])
-            x, c = _apply_layer_decode(block[name], cfg, kind, x, c, pos)
-            cache_all[name] = jax.tree.map(
-                lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
-                cache_all[name], c)
-        return (x, cache_all), None
+                x, cache_all[name], st = _apply_layer_decode(
+                    p, cfg, kind, x, cache_all[name], pos, layer=i,
+                    dropless=moe_dropless, expert_layer=at)
+            else:
+                c = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                           keepdims=False),
+                    cache_all[name])
+                x, c, st = _apply_layer_decode(p, cfg, kind, x, c, pos,
+                                               dropless=moe_dropless,
+                                               expert_layer=at)
+                cache_all[name] = jax.tree.map(
+                    lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                        a, u, i, 0), cache_all[name], c)
+            if count and st is not None:
+                stats.append(st)
+        return (x, cache_all), (jnp.stack(stats) if count else None)
 
     new_cache: dict = {}
+    stats = []
     if cfg.n_periods > 0:
-        (x, new_cache["blocks"]), _ = jax.lax.scan(
+        blocks, experts = _split_experts(params["blocks"], cfg, moe_dropless)
+        (x, new_cache["blocks"]), per_period = jax.lax.scan(
             period_body, (x, cache["blocks"]),
-            (params["blocks"], jnp.arange(cfg.n_periods)))
+            (blocks, jnp.arange(cfg.n_periods)))
+        if count:
+            stats.append(per_period.reshape(-1, 2))
     rem = cfg.layer_pattern[: cfg.n_remainder_layers]
     if rem:
         new_cache["remainder"] = []
         for j, p in enumerate(params["remainder"]):
-            x, c = _apply_layer_decode(p, cfg, rem[j], x,
-                                       cache["remainder"][j], pos)
+            x, c, st = _apply_layer_decode(p, cfg, rem[j], x,
+                                           cache["remainder"][j], pos,
+                                           dropless=moe_dropless)
             new_cache["remainder"].append(c)
+            if count and st is not None:
+                stats.append(st[None])
     logits = _logits(params, cfg, x)[:, 0, :]
+    if count:
+        return logits, new_cache, moe_counts + jnp.concatenate(stats)
     return logits, new_cache

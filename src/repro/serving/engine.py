@@ -43,8 +43,18 @@ def jit_decode(cfg: ArchConfig):
     the cache (argument 2) donated: the step writes each new K/V row into
     the buffers it was given, returns them as the new cache and copies
     nothing cache-sized. The caller drops its reference to the old cache
-    (``step`` replaces it)."""
-    return jax.jit(make_decode_fn(cfg), donate_argnums=(2,))
+    (``step`` replaces it).
+
+    A model with experts takes and returns its MoE counters too
+    (``model.init_moe_counts``, argument 4, donated):
+    (params, tokens, cache, pos, counts) -> (logits, cache, counts)."""
+    if cfg.n_experts == 0:
+        return jax.jit(make_decode_fn(cfg), donate_argnums=(2,))
+
+    def fn(params, tokens, cache, pos, counts):
+        return model.decode_step(params, cfg, tokens, cache, pos,
+                                 moe_counts=counts)
+    return jax.jit(fn, donate_argnums=(2, 4))
 
 
 @dataclasses.dataclass
@@ -60,6 +70,10 @@ class ServingEngine:
     assigned to free slots after prefill and release them on completion.
     This is the data-plane object an LA-IMR 'replica' models: its service
     rate is one decode step across all active slots.
+
+    A model with experts serves them dropless and keeps, on the device,
+    per MoE layer the experts each decode step touched and the rows it
+    routed, summed over steps (``moe_counters``).
     """
 
     def __init__(self, cfg: ArchConfig, params: PyTree, slots: int,
@@ -72,6 +86,7 @@ class ServingEngine:
         self.pos = jnp.zeros((slots,), jnp.int32)
         self.active = np.zeros((slots,), bool)
         self.current = jnp.zeros((slots,), jnp.int32)
+        self.moe_counts = model.init_moe_counts(cfg)
         self._decode = jit_decode(cfg)
         self._merge = jax.jit(_merge_cache, donate_argnums=(0,))
         self._prefill = jax.jit(
@@ -116,13 +131,32 @@ class ServingEngine:
         """One decode step for all slots; returns the new tokens (B,)."""
         with span("engine.step"):
             with span("engine.dispatch"):
-                logits, self.cache = self._decode(self.params, self.current,
-                                                  self.cache, self.pos)
+                if self.moe_counts is None:
+                    logits, self.cache = self._decode(
+                        self.params, self.current, self.cache, self.pos)
+                else:
+                    logits, self.cache, self.moe_counts = self._decode(
+                        self.params, self.current, self.cache, self.pos,
+                        self.moe_counts)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 self.current = nxt
                 self.pos = self.pos + 1
             with span("engine.readback"):
                 return np.asarray(nxt)
+
+    def moe_counters(self) -> Optional[np.ndarray]:
+        """int64 (MoE layers, 2), one read from the device: per MoE layer
+        in layer order, the experts that received at least one row and
+        the rows routed (slots * top_k a step, every slot decoding),
+        each summed over the decode steps since the engine was built or
+        ``reset_moe_counters``. None for a model without experts."""
+        if self.moe_counts is None:
+            return None
+        return np.asarray(self.moe_counts).astype(np.int64)
+
+    def reset_moe_counters(self) -> None:
+        if self.moe_counts is not None:
+            self.moe_counts = jnp.zeros_like(self.moe_counts)
 
     def generate(self, prompts: jax.Array, steps: int) -> GenerationResult:
         """Prefill ``prompts`` (B<=slots, S) then greedy-decode ``steps``."""

@@ -43,6 +43,24 @@ span                      covers
                           engine's slots.
 ========================  ==================================================
 
+Counters, kept on the device and read by the host once, when asked:
+
+==========================  ================================================
+counter                     counts
+==========================  ================================================
+``ServingEngine.moe_        int32 (MoE layers, 2), models with experts
+counters()``                only: per MoE layer, in layer order, the experts
+                            that received at least one row and the rows
+                            routed (``slots * top_k`` a step: the serving
+                            MoE drops none), each summed over decode steps
+                            since the engine was built or
+                            ``reset_moe_counters()``. The decode step adds
+                            them on the device (the counters ride through
+                            the jitted step, donated); ``moe_counters()``
+                            is the one read. A model without experts keeps
+                            none, and its step is unchanged.
+==========================  ================================================
+
 The four children of a flush nest inside ``laimr.plane.flush``:
 ``rates``, ``upload``, ``launch`` and ``readback`` run inside the
 policy's ``decide``, then ``bind``. Policies without a fused backend

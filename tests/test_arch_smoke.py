@@ -2,6 +2,8 @@
 variant of each assigned architecture runs one forward + one train step
 on CPU; output shapes and finiteness are asserted. The FULL configs are
 exercised only by the dry-run (launch/dryrun.py)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -71,7 +73,14 @@ class TestArchSmoke:
         cfg = reduced(get_config(arch_id))
         params = model.init_params(key, cfg)
         batch = make_batch(cfg, key)
-        logits_full, _ = model.forward(params, cfg, batch)
+        # prefill serves experts dropless; the training forward drops
+        # rows past each expert's capacity, so it runs at a capacity no
+        # expert can exceed (every token of the group)
+        full_cfg = cfg
+        if cfg.n_experts:
+            full_cfg = dataclasses.replace(
+                cfg, capacity_factor=cfg.n_experts / cfg.top_k)
+        logits_full, _ = model.forward(params, full_cfg, batch)
         logits_pre, cache = model.prefill(params, cfg, batch)
         # prefill's last-token logits == forward's last position
         assert jnp.allclose(logits_pre, logits_full[:, -1, :],
@@ -93,13 +102,13 @@ class TestParamCounts:
             "recurrentgemma_2b": 2.7e9, "nemotron_4_340b": 340e9,
             "gemma2_27b": 27e9, "dbrx_132b": 132e9, "stablelm_3b": 2.8e9,
             "arctic_480b": 480e9, "whisper_small": 0.24e9,
-            "phi3_medium_14b": 14e9,
+            "phi3_medium_14b": 14e9, "mellum2_12b": 12e9,
         }
         for aid, want in nominal.items():
             got = model.param_count(get_config(aid))
             assert abs(got - want) / want < 0.35, (aid, got, want)
 
     def test_moe_active_lt_total(self):
-        for aid in ("dbrx_132b", "arctic_480b"):
+        for aid in ("dbrx_132b", "arctic_480b", "mellum2_12b"):
             cfg = get_config(aid)
             assert model.active_param_count(cfg) < model.param_count(cfg)

@@ -9,6 +9,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import (decode_attention,
                                             decode_attention_stacked)
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.moe_gmm import moe_gmm
 from repro.kernels.routing_decide import (routing_attain, routing_guard,
                                           routing_topk)
 from repro.kernels.routing_score import build_erlang_table, routing_score
@@ -565,3 +566,64 @@ class TestRoutingAttain:
                                        avail, table, k=2)
         assert not bool(jnp.any(gok)) and not bool(jnp.any(rok))
         assert bool(jnp.all(gi == -1)) and bool(jnp.all(ri == -1))
+
+
+class TestMoeGmm:
+    # (rows, K, N, group sizes, row block): groups of every size with
+    # empty ones between; every row on one expert; rows that fill no
+    # whole block; one expert per block; a weight too wide for one VMEM
+    # block (two lane blocks of N)
+    @pytest.mark.parametrize("m,k,n,sizes,block_m", [
+        (40, 128, 256, [10, 0, 25, 5], 16),
+        (40, 128, 256, [0, 0, 40, 0], 16),
+        (37, 128, 128, [3, 0, 0, 10, 4, 0, 6, 2], 16),
+        (64, 256, 384, [16, 16, 16, 16], 16),
+        (24, 2048, 2304, [0, 20, 4], 16),
+    ])
+    def test_matches_ref(self, m, k, n, sizes, block_m):
+        kx, kw = jax.random.split(jax.random.PRNGKey(m + n))
+        x = jax.random.normal(kx, (m, k), jnp.float32).astype(jnp.bfloat16)
+        w = jax.random.normal(kw, (len(sizes), k, n),
+                              jnp.float32).astype(jnp.bfloat16)
+        sizes = jnp.asarray(sizes, jnp.int32)
+        got = moe_gmm(x, w, sizes, block_m=block_m, interpret=True)
+        want = ref.moe_gmm(x, w, sizes)
+        # the same bf16 products accumulated in f32, rounded once to bf16
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_layer_of_a_stack_matches_ref(self, layer):
+        """The decode step's form: one layer's experts of a stack, the
+        layer index a scalar-prefetch operand."""
+        kx, kw = jax.random.split(jax.random.PRNGKey(layer))
+        x = jax.random.normal(kx, (24, 128), jnp.float32)
+        w = jax.random.normal(kw, (3, 4, 128, 256), jnp.float32)
+        sizes = jnp.asarray([5, 0, 12, 7], jnp.int32)
+        got = moe_gmm(x, w, sizes, jnp.int32(layer), interpret=True)
+        want = ref.moe_gmm(x, w[layer], sizes)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   **tol(jnp.float32))
+
+    def test_ref_is_the_grouped_product(self):
+        """Row r of the oracle is x[r] @ w[group of r]; rows past the
+        groups are zero."""
+        x = jax.random.normal(jax.random.PRNGKey(0), (9, 8), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(1), (3, 8, 5), jnp.float32)
+        got = np.asarray(ref.moe_gmm(x, w, jnp.asarray([3, 0, 4])))
+        group = [0, 0, 0, 2, 2, 2, 2]
+        want = np.stack([np.asarray(x[r]) @ np.asarray(w[g])
+                         for r, g in enumerate(group)] + [np.zeros(5)] * 2)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+    def test_visits_skip_empty_experts(self):
+        """Experts that received no row are never visited, so their
+        weights are never read; every row block is visited."""
+        from repro.kernels.moe_gmm import _visits
+        tile, group, widx, offsets, n_real = _visits(
+            jnp.asarray([0, 5, 0, 0, 11, 0], jnp.int32), 32, 16)
+        n = int(n_real[0])
+        assert set(np.asarray(widx[:n]).tolist()) == {1, 4}
+        assert set(np.asarray(tile[:n]).tolist()) == {0, 1}
+        np.testing.assert_array_equal(offsets, [0, 0, 5, 5, 5, 16, 16])
+        assert int(group[n - 1]) == 6      # the zero tail, rows 16..31

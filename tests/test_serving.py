@@ -116,15 +116,15 @@ def _slice_and_write_back_step(params, cfg, tokens, cache, pos):
                 name = f"layer{j}"
                 p = jax.tree.map(lambda a: a[i], params["blocks"][name])
                 c = jax.tree.map(lambda a: a[i], stack[name])
-                x, c = transformer._apply_layer_decode(p, cfg, kind, x, c,
-                                                       pos)
+                x, c, _ = transformer._apply_layer_decode(p, cfg, kind, x,
+                                                          c, pos)
                 stack = {**stack, name: jax.tree.map(
                     lambda a, u: a.at[i].set(u), stack[name], c)}
         new["blocks"] = stack
     if cfg.n_remainder_layers:
         new["remainder"] = []
         for j, p in enumerate(params["remainder"]):
-            x, c = transformer._apply_layer_decode(
+            x, c, _ = transformer._apply_layer_decode(
                 p, cfg, cfg.layer_pattern[j], x, cache["remainder"][j], pos)
             new["remainder"].append(c)
     return transformer._logits(params, cfg, x)[:, 0, :], new
@@ -133,8 +133,9 @@ def _slice_and_write_back_step(params, cfg, tokens, cache, pos):
 # (architecture, layers, kv heads, kernel path): global attention whose
 # ring wraps; local + global with a local remainder layer; rglru + local
 # with two unrolled rglru remainder layers; phi3's 4 query heads per kv
-# head; the Pallas stacked kernel (interpret mode) inside the step; and
-# the grouped-einsum twin
+# head; the Pallas stacked kernel (interpret mode) inside the step; the
+# grouped-einsum twin; and mellum2's three sliding layers and one YaRN
+# full layer with dropless MoE, on the oracles and on the Pallas kernels
 PARITY = [
     ("stablelm_3b", 2, None, "ref"),
     ("gemma2_27b", 5, None, "ref"),
@@ -143,6 +144,8 @@ PARITY = [
     ("stablelm_3b", 2, None, "interp"),
     ("gemma2_27b", 5, None, "interp"),
     ("phi3_medium_14b", 2, 1, "fused"),
+    ("mellum2_12b", 4, None, "ref"),
+    ("mellum2_12b", 4, None, "interp"),
 ]
 
 
