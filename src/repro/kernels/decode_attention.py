@@ -9,8 +9,11 @@ kv-block grid axis — the TPU-native
 flash-decode: the cache streams HBM->VMEM exactly once, and the fp32
 accumulator never leaves VMEM.
 
-GQA by indexing kv-head h // rep inside the block, validity masking from
-kv_pos (handles ring-buffer wraparound and sliding windows without any
+GQA by looping over kv heads: kv head g's K and V are sliced out of the
+block and upcast once, and its rep = H // Hkv query heads (rows
+g·rep … (g+1)·rep) attend them as one group, one score dot and one value
+dot (at rep 1, one query head at a time). Validity masking from kv_pos
+(handles ring-buffer wraparound and sliding windows without any
 position arithmetic in the layer code).
 
 Two entry points run the same kernel body: ``decode_attention`` over one
@@ -67,26 +70,27 @@ def _attend(kv_pos, q_ref, k_ref, v_ref, qpos_ref, o_ref,
         valid &= kv_pos > (q_pos - window)
 
     d = q_ref.shape[2]                      # the cache rows may be wider
-    for h in range(q_ref.shape[1]):
-        q = q_ref[0, h:h + 1, :].astype(jnp.float32)          # (1, D)
-        k = k_ref[0, :, h // rep, :d].astype(jnp.float32)     # (bkv, D)
-        v = v_ref[0, :, h // rep, :d].astype(jnp.float32)
+    for g in range(k_ref.shape[2]):
+        rows = slice(g * rep, (g + 1) * rep)  # kv head g's query group
+        q = q_ref[0, rows, :].astype(jnp.float32)             # (rep, D)
+        k = k_ref[0, :, g, :d].astype(jnp.float32)            # (bkv, D)
+        v = v_ref[0, :, g, :d].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap > 0:
             s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid, s, NEG_INF)                      # (1, bkv)
+        s = jnp.where(valid, s, NEG_INF)                      # (rep, bkv)
 
-        m_prev = m_ref[h:h + 1, :]                            # (1, 1)
+        m_prev = m_ref[rows, :]                               # (rep, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[h:h + 1, :] = l_ref[h:h + 1, :] * corr + jnp.sum(
+        l_ref[rows, :] = l_ref[rows, :] * corr + jnp.sum(
             p, axis=1, keepdims=True)
-        acc_ref[h:h + 1, :] = acc_ref[h:h + 1, :] * corr + jax.lax.dot_general(
+        acc_ref[rows, :] = acc_ref[rows, :] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[h:h + 1, :] = m_new
+        m_ref[rows, :] = m_new
 
     @pl.when(ikv == n_kv_blocks - 1)
     def _finish():

@@ -90,6 +90,8 @@ class TestDecodeAttention:
         (1, 1, 1, 32, 128),
         (3, 4, 2, 64, 256),
         (2, 8, 1, 64, 512),
+        (2, 32, 4, 128, 256),   # Mellum2's groups: rep 8, one sublane tile
+        (2, 12, 2, 64, 256),    # rep 6: groups start off the sublane tile
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, b, h, hkv, d, c, dtype):
@@ -105,6 +107,18 @@ class TestDecodeAttention:
         want = ref.decode_attention(q, k, v, kv_pos, q_pos)
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32), **tol(dtype))
+
+    @pytest.mark.parametrize("h,hkv", [(4, 4), (8, 2), (32, 4), (12, 2)])
+    def test_one_dot_pair_per_kv_head(self, h, hkv):
+        """A KV head's query group attends as one score dot and one value
+        dot: the kernel body holds 2 x Hkv dots, whatever the group."""
+        s = jax.ShapeDtypeStruct
+        kv = s((2, 128, hkv, 128), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: decode_attention(*a, block_kv=64))(
+                s((2, h, 64), jnp.bfloat16), kv, kv, s((2, 128), jnp.int32),
+                s((2,), jnp.int32))
+        assert str(jaxpr).count("dot_general") == 2 * hkv
 
     def test_window(self):
         rng = np.random.default_rng(1)
@@ -144,6 +158,8 @@ class TestDecodeAttention:
         (4, 4, 48, [300, 517], 32),    # sliding window over wrapped rings
         (8, 2, 0, [90, 517], 32),      # GQA, rep 4; one ring not yet full
         (8, 2, 48, [90, 517], 128),    # rows padded to a 128-lane tile
+        (32, 4, 48, [300, 517], 128),  # Mellum2's rep 8, sliding layer
+        (32, 4, 0, [90, 517], 128),    # rep 8, full layer, one ring part-full
     ])
     def test_stacked_matches_ref_on_the_layer(self, layer, h, hkv, window,
                                               q_pos, w):

@@ -5,7 +5,8 @@ compiler accepts it. These tests compile each kernel for one chip of a
 described (not attached) v5e at the widths the chip runs: every routing
 kernel at single- and multi-block windows, both attention kernels at
 StableLM-3B widths (H=32, D=80), the stacked decode kernel at
-Mellum2-12B widths (H=32, Hkv=4, D=128, window 1024) and the grouped
+Mellum2-12B widths (H=32, Hkv=4, D=128, window 1024) and at the query
+groups of DBRX, Phi-3 and Arctic (6, 4 and 7 heads a KV head), the grouped
 expert matmul at Mellum2's expert widths (d=2304, f=896) at the row
 counts of its prefill and decode. Nothing runs, so no result is checked
 here: a compile that passes only rules out what Mosaic refuses. Whole
@@ -138,6 +139,18 @@ def test_stacked_decode_attention_compiles_at_mellum2_width(one_chip, c,
              s((9, 8, c, 4, 128), jnp.bfloat16),
              s((9, 8, c, 4, 128), jnp.bfloat16), s((9, 8, c), jnp.int32),
              s((8,), jnp.int32), s((), jnp.int32))
+
+
+@pytest.mark.parametrize("h,hkv", [(48, 8), (40, 10), (56, 8)])
+def test_stacked_decode_attention_compiles_at_gqa_width(one_chip, h, hkv):
+    """Query groups that do not fill a sublane tile: DBRX (rep 6), Phi-3
+    (rep 4) and Arctic (rep 7), D 128 over 4096 positions."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    _compile(da.decode_attention_stacked, s((8, h, 128), jnp.bfloat16),
+             s((4, 8, 4096, hkv, 128), jnp.bfloat16),
+             s((4, 8, 4096, hkv, 128), jnp.bfloat16),
+             s((4, 8, 4096), jnp.int32), s((8,), jnp.int32),
+             s((), jnp.int32))
 
 
 # rows: a prefill wave of 7 x 2048 tokens, top-8; a decode step of 7
